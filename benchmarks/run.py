@@ -49,6 +49,8 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write per-bench results as JSON")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     mods = ALL if not args.only else [
         m for m in ALL if any(m.startswith(p) for p in args.only.split(","))]
     print("name,us_per_call,derived")

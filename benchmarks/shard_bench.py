@@ -14,9 +14,10 @@ Runs the mesh-native hot paths (ISSUE 5) on a forced 4-host-device
   balanced slot/cluster parallelism).
 
 The parent process may already own a single-device jax runtime (the
-benchmarks/run.py driver), so the measurement runs in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4``; pass ``--child``
-to run the measurement directly.
+benchmarks/run.py runner, which on a TPU host holds the chip), so the
+measurement runs in a CPU subprocess (``JAX_PLATFORMS=cpu``,
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``) that never asks
+for the chip; pass ``--child`` to run the measurement directly.
 """
 from __future__ import annotations
 
@@ -165,7 +166,7 @@ def main() -> None:
         return
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
+               JAX_PLATFORMS="cpu",      # host devices: never the chip
                REPRO_SHARD_BENCH_CHILD="1",
                PYTHONPATH="src" + (os.pathsep + os.environ["PYTHONPATH"]
                                    if os.environ.get("PYTHONPATH") else ""))

@@ -33,7 +33,8 @@ cfg = cfg.with_(peft=dataclasses.replace(cfg.peft, head_dim_out=5))
 params = M.init(cfg, jax.random.PRNGKey(0))
 task = ClassificationTask(5, cfg.vocab_size, 32, seed=0)
 
-mesh = jax.make_mesh((N_STAGES,), ("stage",))
+mesh = jax.make_mesh((N_STAGES,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 stages = split_for_stages(params, cfg, N_STAGES)
 print(f"[sl] split {cfg.n_layers} layers across {N_STAGES} clients "
       f"({cfg.n_layers // N_STAGES} layers each)")

@@ -33,10 +33,11 @@ import jax
 
 from repro.core import telemetry
 
-# jax logs one "Compiling <name> with global shapes and types [...]" line
-# per actual XLA compilation (cache hits are silent) when log_compiles is
-# on; tracing/lowering lines are deliberately NOT counted.
-_COMPILE_RE = re.compile(r"^Compiling (.+?) with global shapes")
+# jax logs one "Compiling jit(<name>) with global shapes and types [...]"
+# line per actual XLA compilation (cache hits are silent) when
+# log_compiles is on; tracing/lowering lines are deliberately NOT counted.
+# The recorded name is the function's own, without the jit(...) wrapper.
+_COMPILE_RE = re.compile(r"^Compiling jit\((.+?)\) with global shapes")
 
 
 class CompileBudgetExceeded(RuntimeError):

@@ -117,6 +117,14 @@ class ModelConfig:
     def with_(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
+    def with_depth(self, n_layers: int) -> "ModelConfig":
+        """The same widths with only ``n_layers`` of the layers: the cut
+        that fits a model to one chip's share. Nothing else changes."""
+        if not 0 < n_layers <= self.n_layers:
+            raise ValueError(f"{self.name}: depth must be in 1..{self.n_layers}, "
+                             f"got {n_layers}")
+        return self.with_(n_layers=n_layers)
+
     # -- analytic parameter counts (for rooflines / MODEL_FLOPS) ------------
     def param_count(self) -> int:
         """Total backbone parameters (analytic, matches init to within ties)."""

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import embed, rmsnorm
@@ -130,11 +129,11 @@ def pipeline_classify(params: dict, stage_tree: dict, tokens: jax.Array,
         # psum replicates — only the end stage holds nonzero logits.
         return jax.lax.psum(out, "stage")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(P("stage"), P("stage"), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(stage_tree["layers"], stage_tree["adapters"], toks_mb)
     return out.reshape(B, -1)

@@ -13,6 +13,15 @@ written on the last chunk. GQA is expressed in the index_maps: the
 sublane dim of a single ``(g, D)`` q tile, so grouped queries ride along for
 free instead of duplicating KV reads per query head.
 
+Layout (what the TPU compiler accepts): the cache is viewed as
+``(B, T, Hkv * Dp)`` and the pool as ``(n_blocks, bs, Hkv * Dp)``, so one
+KV head's chunk is a ``(block, Dp)`` lane-aligned column slab; per-row
+query positions (and the paged block table) are scalar-prefetched into
+SMEM; the dense kernel's kv positions ride as a ``(B, nk, block_kv)`` plane
+whose whole-row block stays resident in VMEM across the chunk sweep (each
+step reads its ``(1, block_kv)`` row), which keeps any ``block_kv`` that is
+a multiple of 8 legal.
+
 Masking is position-based and length-aware (kernels/ref.py semantics):
 unwritten cache slots carry the ``+1e9`` sentinel position and are never
 visible — decode never reads garbage K/V even though the buffer is padded to
@@ -39,7 +48,7 @@ NEG_INF = -1e30
 def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float, causal: bool,
             window: int, nk: int):
-    j = pl.program_id(2)
+    b, j = pl.program_id(0), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -47,15 +56,15 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0, :, :].astype(jnp.float32)              # (g, Dp)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)              # (bkv, Dp)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)                      # (g, Dp)
+    k = k_ref[...].astype(jnp.float32)                      # (bkv, Dp)
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    qp = qpos_ref[0, 0]                                     # scalar position
-    kpos = kpos_ref[0, :][None, :]                          # (1, bkv)
+    qp = qpos_ref[b]                                        # SMEM scalar
+    kpos = kpos_ref[pl.ds(j, 1), :]                         # (1, bkv)
     vis = (kpos <= qp) if causal else (kpos < 10 ** 8)     # sentinel padding
     if window and window > 0:
         vis = jnp.logical_and(vis, (qp - kpos) < window)
@@ -79,7 +88,7 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == nk - 1)
     def _done():
         out = acc_new / jnp.maximum(l_new, 1e-30)
-        o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _pad(x, axis, mult, value=0):
@@ -106,7 +115,7 @@ def _paged_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
     ``> q_pos`` and mask to an exact f32 zero, which is what makes the
     paged path bit-identical to the dense kernel at ``block_kv == bs``.
     """
-    j = pl.program_id(2)
+    b, j = pl.program_id(0), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -114,14 +123,14 @@ def _paged_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0, :, :].astype(jnp.float32)              # (g, Dp)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)              # (bs, Dp)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)                      # (g, Dp)
+    k = k_ref[...].astype(jnp.float32)                      # (bs, Dp)
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    qp = qpos_ref[0, 0]                                     # scalar position
+    qp = qpos_ref[b]                                        # SMEM scalar
     kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     s = jnp.where(kpos <= qp, s, NEG_INF)                   # causal only
 
@@ -142,7 +151,7 @@ def _paged_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == nk - 1)
     def _done():
         out = acc_new / jnp.maximum(l_new, 1e-30)
-        o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -169,24 +178,24 @@ def flash_decode_paged_pallas(q, k_pool, v_pool, table, *, q_pos,
 
     Dp = max(128, D + (-D) % 128)
     qp4 = _pad(q.reshape(B, Hkv, g, D), 3, Dp)
-    kp = _pad(k_pool, 3, Dp)
-    vp = _pad(v_pool, 3, Dp)
-    qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))[:, None]
+    kp = _pad(k_pool, 3, Dp).reshape(nb, bs, Hkv * Dp)
+    vp = _pad(v_pool, 3, Dp).reshape(nb, bs, Hkv * Dp)
+    qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
     tbl = jnp.clip(table.astype(jnp.int32), 0, nb - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, Hkv, maxb),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, j, tbl: (b, 0)),
-            pl.BlockSpec((1, 1, g, Dp), lambda b, h, j, tbl: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, Dp),
-                         lambda b, h, j, tbl: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, Dp),
-                         lambda b, h, j, tbl: (tbl[b, j], 0, h, 0)),
+            pl.BlockSpec((None, None, g, Dp),
+                         lambda b, h, j, tbl, qpos: (b, h, 0, 0)),
+            pl.BlockSpec((None, bs, Dp),
+                         lambda b, h, j, tbl, qpos: (tbl[b, j], 0, h)),
+            pl.BlockSpec((None, bs, Dp),
+                         lambda b, h, j, tbl, qpos: (tbl[b, j], 0, h)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, Dp),
-                               lambda b, h, j, tbl: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((None, None, g, Dp),
+                               lambda b, h, j, tbl, qpos: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, Dp), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
@@ -219,31 +228,37 @@ def flash_decode_pallas(q, k, v, *, q_pos, kv_pos, window: int = 0,
     qp4 = _pad(q.reshape(B, Hkv, g, D), 3, Dp)
     kp = _pad(_pad(k, 1, bkv), 3, Dp)
     vp = _pad(_pad(v, 1, bkv), 3, Dp)
-    qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))[:, None]
-    kvpos = _pad(jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T)),
-                 1, bkv, value=10 ** 9)                     # padding invisible
     Tp = kp.shape[1]
     nk = Tp // bkv
+    kp = kp.reshape(B, Tp, Hkv * Dp)
+    vp = vp.reshape(B, Tp, Hkv * Dp)
+    qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
+    kvpos = _pad(jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T)),
+                 1, bkv, value=10 ** 9).reshape(B, nk, bkv)  # pad invisible
 
-    grid = (B, Hkv, nk)
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, causal=causal,
-                          window=window, nk=nk),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, Hkv, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, j: (b, 0)),
-            pl.BlockSpec((1, bkv), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, 1, g, Dp), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, bkv, 1, Dp), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bkv, 1, Dp), lambda b, h, j: (b, j, h, 0)),
+            pl.BlockSpec((None, nk, bkv), lambda b, h, j, qpos: (b, 0, 0)),
+            pl.BlockSpec((None, None, g, Dp),
+                         lambda b, h, j, qpos: (b, h, 0, 0)),
+            pl.BlockSpec((None, bkv, Dp), lambda b, h, j, qpos: (b, j, h)),
+            pl.BlockSpec((None, bkv, Dp), lambda b, h, j, qpos: (b, j, h)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, Dp), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dp), q.dtype),
+        out_specs=pl.BlockSpec((None, None, g, Dp),
+                               lambda b, h, j, qpos: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, Dp), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, causal=causal,
+                          window=window, nk=nk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dp), q.dtype),
         interpret=interpret,
     )(qpos, kvpos, qp4, kp, vp)
     return out[..., :D].reshape(B, Hq, D)

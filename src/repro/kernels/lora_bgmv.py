@@ -26,8 +26,9 @@ Two shapes, two kernels:
 Both produce bit-identical per-row results to the single-LoRA kernel run
 with that row's adapter (the mixed-domain == per-domain serving parity the
 engine tests assert). Dispatched from ops.py::lora_bgmv behind the usual
-``xla|pallas|interpret`` switch. Block sizes follow lora_matmul.py and are
-validated in interpret mode only — revalidate on real TPU hardware.
+``xla|pallas|interpret`` switch. Block sizes follow lora_matmul.py; both
+kernels compile for v5e at qwen2-7b widths (tests/test_tpu_compile.py), but
+no block size has been tuned on the chip.
 """
 # tracelint: kernel-op=lora_bgmv oracle=lora_bgmv
 from __future__ import annotations
@@ -140,14 +141,14 @@ def _seq_kernel(ids_ref, x_ref, w_ref, a_ref, b_ref, bias_ref, o_ref,
                 acc_ref, u_ref, *, nk: int, scale: float, has_bias: bool):
     # ids_ref was consumed by the index_maps; the a/b blocks arriving here
     # are already THIS sequence's adapter pair.
-    kk = pl.program_id(2)
+    kk = pl.program_id(3)
 
     @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         u_ref[...] = jnp.zeros_like(u_ref)
 
-    x = x_ref[0].astype(jnp.float32)                       # (Sp, bk)
+    x = x_ref[...].astype(jnp.float32)                      # (bs, bk)
     acc_ref[...] += jax.lax.dot(x, w_ref[...].astype(jnp.float32),
                                 preferred_element_type=jnp.float32)
     u_ref[...] += jax.lax.dot(x, a_ref[0].astype(jnp.float32),
@@ -160,28 +161,29 @@ def _seq_kernel(ids_ref, x_ref, w_ref, a_ref, b_ref, bias_ref, o_ref,
             preferred_element_type=jnp.float32)
         if has_bias:
             y = y + bias_ref[0, :].astype(jnp.float32)[None, :]
-        o_ref[0] = y.astype(o_ref.dtype)
+        o_ref[...] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "block_n", "block_k", "interpret"))
+    "scale", "block_s", "block_n", "block_k", "interpret"))
 def lora_bgmv_seq_pallas(x, w, a, b, adapter_ids, scale: float = 1.0,
                          bias: Optional[jax.Array] = None, *,
-                         block_n: int = 512, block_k: int = 512,
-                         interpret: bool = False):
+                         block_s: int = 512, block_n: int = 512,
+                         block_k: int = 512, interpret: bool = False):
     """x: (B, S, K); w: (K, N); a: (n_slots, K, r); b: (n_slots, r, N);
     adapter_ids: (B,) int32. Returns (B, S, N) in x.dtype.
 
-    The whole (padded) sequence is one block — shrink S upstream (or extend
-    to an S grid dim) if ``S * block_k`` floats outgrow VMEM.
+    The sequence is tiled into ``block_s`` row blocks (a grid dim), so the
+    VMEM footprint is bounded by the tile sizes, not by the prompt length.
     """
     B, S, K = x.shape
     N = w.shape[1]
     n_slots, _, r = a.shape
     bn, bk = min(block_n, N), min(block_k, K)
+    bs = min(block_s, S + (-S) % 8)
     rp = max(r + (-r) % 128, 128)
 
-    xp = _pad(_pad(x, 1, 8), 2, bk)
+    xp = _pad(_pad(x, 1, bs), 2, bk)
     wp = _pad(_pad(w, 0, bk), 1, bn)
     ap = _pad(_pad(a, 1, bk), 2, rp)
     bp = _pad(_pad(b, 1, rp), 2, bn)
@@ -190,21 +192,25 @@ def lora_bgmv_seq_pallas(x, w, a, b, adapter_ids, scale: float = 1.0,
                  1, bn)
     Sp, Kp = xp.shape[1], xp.shape[2]
     Np = wp.shape[1]
-    nn, nk = Np // bn, Kp // bk
+    ns, nn, nk = Sp // bs, Np // bn, Kp // bk
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, nn, nk),
+        grid=(B, ns, nn, nk),
         in_specs=[
-            pl.BlockSpec((1, Sp, bk), lambda bi, j, k, ids: (bi, 0, k)),
-            pl.BlockSpec((bk, bn), lambda bi, j, k, ids: (k, j)),
-            pl.BlockSpec((1, bk, rp), lambda bi, j, k, ids: (ids[bi], k, 0)),
-            pl.BlockSpec((1, rp, bn), lambda bi, j, k, ids: (ids[bi], 0, j)),
-            pl.BlockSpec((1, bn), lambda bi, j, k, ids: (0, j)),
+            pl.BlockSpec((None, bs, bk),
+                         lambda bi, si, j, k, ids: (bi, si, k)),
+            pl.BlockSpec((bk, bn), lambda bi, si, j, k, ids: (k, j)),
+            pl.BlockSpec((1, bk, rp),
+                         lambda bi, si, j, k, ids: (ids[bi], k, 0)),
+            pl.BlockSpec((1, rp, bn),
+                         lambda bi, si, j, k, ids: (ids[bi], 0, j)),
+            pl.BlockSpec((1, bn), lambda bi, si, j, k, ids: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, Sp, bn), lambda bi, j, k, ids: (bi, 0, j)),
-        scratch_shapes=[pltpu.VMEM((Sp, bn), jnp.float32),
-                        pltpu.VMEM((Sp, rp), jnp.float32)],
+        out_specs=pl.BlockSpec((None, bs, bn),
+                               lambda bi, si, j, k, ids: (bi, si, j)),
+        scratch_shapes=[pltpu.VMEM((bs, bn), jnp.float32),
+                        pltpu.VMEM((bs, rp), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_seq_kernel, nk=nk, scale=scale, has_bias=has_bias),

@@ -6,15 +6,22 @@ Every hot-spot op has three interchangeable implementations:
                   structure as the Pallas kernel). This is what the 512-way
                   CPU dry-run lowers, so the roofline reflects the intended
                   kernel structure (Mosaic only lowers on real TPUs).
-- ``pallas``    — the TPU-target ``pl.pallas_call`` kernel.
+- ``pallas``    — the TPU-target ``pl.pallas_call`` kernel. Selecting it
+                  on any platform but ``tpu`` raises: there is no quiet
+                  fallback.
 - ``interpret`` — the same Pallas kernel with ``interpret=True`` (CPU
                   correctness path used by tests).
 
-Select globally with :func:`set_backend` or per-call with ``backend=``.
+Unless :func:`set_backend` (or the :func:`backend` context) chose one, the
+backend follows the platform: ``pallas`` on TPU, ``xla`` elsewhere — so
+every entry point (serve, train, the engine, the integrated runtime) runs
+the kernels on the chip without opting in. A per-call ``backend=``
+overrides both. The choice is read while a jitted function traces.
 """
 from __future__ import annotations
 
 import contextlib
+import math
 from functools import partial
 from typing import Optional
 
@@ -23,7 +30,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 
-_BACKEND = "xla"
+_BACKENDS = ("xla", "pallas", "interpret")
+_BACKEND: Optional[str] = None         # None: follow the platform
 NEG_INF = -1e30
 _FLASH_BQ, _FLASH_BKV = 512, 1024     # default tiles; perf knob below
 
@@ -34,21 +42,34 @@ def set_flash_blocks(bq: int, bkv: int) -> None:
     _FLASH_BQ, _FLASH_BKV = bq, bkv
 
 
-def set_backend(name: str) -> None:
-    global _BACKEND
-    if name not in ("xla", "pallas", "interpret"):
+def _check(name: str) -> str:
+    if name not in _BACKENDS:
         raise ValueError(f"unknown kernel backend {name!r}: expected "
                          "'xla', 'pallas', or 'interpret'")
-    _BACKEND = name
+    if name == "pallas" and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"kernel backend 'pallas' needs a TPU, but the platform is "
+            f"{jax.default_backend()!r} (use 'interpret' to run the Pallas "
+            "kernels on CPU, or 'xla')")
+    return name
+
+
+def set_backend(name: Optional[str]) -> None:
+    """Pin the kernel backend; ``None`` returns to following the platform."""
+    global _BACKEND
+    _BACKEND = None if name is None else _check(name)
 
 
 def get_backend() -> str:
-    return _BACKEND
+    """The backend a dispatch traced now would use."""
+    if _BACKEND is not None:
+        return _BACKEND
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 @contextlib.contextmanager
 def backend(name: str):
-    prev = get_backend()
+    prev = _BACKEND
     set_backend(name)
     try:
         yield
@@ -56,8 +77,68 @@ def backend(name: str):
         set_backend(prev)
 
 
-def _pick(b: Optional[str]) -> str:
-    return b or _BACKEND
+def _pick(b: Optional[str], *, tpu_kernel: bool = True) -> str:
+    """Resolve a call's backend. ``tpu_kernel=False`` marks an op whose
+    Pallas kernel the TPU compiler still refuses (the scans): following
+    the platform, it stays on ``xla``; an explicit choice is honoured."""
+    if b:
+        return _check(b)
+    impl = get_backend()
+    if impl == "pallas" and not tpu_kernel and _BACKEND is None:
+        return "xla"
+    return impl
+
+
+def _on_mesh(kernel, args, in_axes, out_axes, psum=()):
+    """Call a Pallas kernel inside the active sharding context.
+
+    XLA cannot partition a Mosaic kernel, so under a mesh (a
+    ``rules.use_rules`` context: the engine's mesh waves, the mesh HFSL
+    round) the call runs in a ``shard_map`` and each device runs the kernel
+    on its own block. ``in_axes`` names each operand's dims: ``"batch"``
+    for rows or sequences, ``"heads"`` for the tensor-parallel dim
+    (attention heads; a projection's output or contracted columns), None
+    for a dim every device holds whole. A name takes the mesh axes the
+    active rules give it, unless a dim it names does not divide by them;
+    then those dims are whole everywhere. ``out_axes`` names the result's
+    dims, and ``psum`` the split dims the kernel contracts over, whose
+    partial results are summed (a list of each for several results).
+    Without a mesh this is a plain call.
+    """
+    from repro.sharding.rules import active_rules
+    mesh, rules = active_rules()
+    if mesh is None:
+        return kernel(*args)
+    from jax.sharding import PartitionSpec as P
+    taken, split = set(), {}
+    for name in dict.fromkeys(n for ax in in_axes for n in ax if n):
+        tgt = rules.get(name)
+        tgt = () if tgt is None else (tgt,) if isinstance(tgt, str) else tgt
+        tgt = tuple(a for a in tgt if a in mesh.axis_names and a not in taken)
+        k = math.prod(mesh.shape[a] for a in tgt)
+        if all(x.shape[i] % k == 0 for ax, x in zip(in_axes, args)
+               for i, n in enumerate(ax) if n == name):
+            split[name] = tgt
+            taken.update(tgt)
+
+    def spec(ax):
+        return P(*(split.get(n) or None for n in ax))
+
+    several = isinstance(out_axes, list)
+    outs = out_axes if several else [out_axes]
+    sums = psum if several else [psum]
+    red = [tuple(a for n in names for a in split.get(n, ())) for names in sums]
+
+    def body(*a):
+        ys = kernel(*a)
+        ys = [jax.lax.psum(y, r) if r else y
+              for y, r in zip(ys if several else [ys], red)]
+        return tuple(ys) if several else ys[0]
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=tuple(spec(ax) for ax in in_axes),
+        out_specs=tuple(spec(ax) for ax in outs) if several else spec(outs[0]),
+        check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -86,14 +167,50 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_kv = block_kv or _FLASH_BKV
     impl = _pick(backend)
     if impl in ("pallas", "interpret"):
-        from repro.kernels import flash_attention as fk
-        return fk.flash_attention_pallas(
-            q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window, causal=causal,
-            scale=scale, block_q=block_q, block_kv=block_kv,
-            interpret=(impl == "interpret"))
+        return _flash_kernel(impl, window, causal, scale, block_q, block_kv,
+                             q, k, v, q_pos, kv_pos)
     return _flash_xla(q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window,
                       causal=causal, scale=scale, block_q=block_q,
                       block_kv=block_kv)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
+def _flash_kernel(impl, window, causal, scale, block_q, block_kv,
+                  q, k, v, q_pos, kv_pos):
+    """Forward on the Pallas kernel; the backward (training through
+    attention) differentiates the blocked XLA algorithm — the same
+    semantics, recomputed — since the kernel has no backward of its own."""
+    from repro.kernels import flash_attention as fk
+    qp, kp = jnp.asarray(q_pos, jnp.int32), jnp.asarray(kv_pos, jnp.int32)
+    bshd = ("batch", None, "heads", None)
+    return _on_mesh(
+        lambda q, k, v, qp, kp: fk.flash_attention_pallas(
+            q, k, v, q_pos=qp, kv_pos=kp, window=window, causal=causal,
+            scale=scale, block_q=block_q, block_kv=block_kv,
+            interpret=(impl == "interpret")),
+        (q, k, v, qp, kp),
+        (bshd, bshd, bshd, (None,) * qp.ndim, (None,) * kp.ndim), bshd)
+
+
+def _flash_kernel_fwd(impl, window, causal, scale, block_q, block_kv,
+                      q, k, v, q_pos, kv_pos):
+    out = _flash_kernel(impl, window, causal, scale, block_q, block_kv,
+                        q, k, v, q_pos, kv_pos)
+    return out, (q, k, v, q_pos, kv_pos)
+
+
+def _flash_kernel_bwd(impl, window, causal, scale, block_q, block_kv,
+                      res, dout):
+    q, k, v, q_pos, kv_pos = res
+    _, vjp = jax.vjp(
+        lambda q_, k_, v_: _flash_xla(
+            q_, k_, v_, q_pos=q_pos, kv_pos=kv_pos, window=window,
+            causal=causal, scale=scale, block_q=block_q, block_kv=block_kv),
+        q, k, v)
+    return (*vjp(dout), None, None)
+
+
+_flash_kernel.defvjp(_flash_kernel_fwd, _flash_kernel_bwd)
 
 
 def _flash_xla(q, k, v, *, q_pos, kv_pos, window, causal, scale,
@@ -247,10 +364,17 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 [jnp.full((B, n_p), -1, jnp.int32),
                  jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T))],
                 axis=1)
-        return fdk.flash_decode_pallas(
-            q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window,
-            causal=causal, scale=scale, block_kv=block_kv,
-            interpret=(impl == "interpret"))
+        T = k.shape[1]
+        qp = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
+        kp = jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T))
+        bhd, bthd = ("batch", "heads", None), ("batch", None, "heads", None)
+        return _on_mesh(
+            lambda q, k, v, qp, kp: fdk.flash_decode_pallas(
+                q, k, v, q_pos=qp, kv_pos=kp, window=window, causal=causal,
+                scale=scale, block_kv=block_kv,
+                interpret=(impl == "interpret")),
+            (q, k, v, qp, kp),
+            (bhd, bthd, bthd, ("batch",), ("batch", None)), bhd)
     return _flash_decode_xla(q, k, v, q_pos=q_pos, kv_pos=kv_pos,
                              prefix_k=prefix_k, prefix_v=prefix_v,
                              window=window, causal=causal, scale=scale)
@@ -344,9 +468,14 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     B, maxb = table.shape
     if impl in ("pallas", "interpret") and prefix_k is None:
         from repro.kernels import flash_decode as fdk
-        return fdk.flash_decode_paged_pallas(
-            q, k_pool, v_pool, table, q_pos=q_pos, scale=scale,
-            interpret=(impl == "interpret"))
+        qp = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
+        bhd, pool = ("batch", "heads", None), (None, None, "heads", None)
+        return _on_mesh(                 # any row may name any pool block
+            lambda q, kp, vp, t, qp: fdk.flash_decode_paged_pallas(
+                q, kp, vp, t, q_pos=qp, scale=scale,
+                interpret=(impl == "interpret")),
+            (q, k_pool, v_pool, table, qp),
+            (bhd, pool, pool, ("batch", None), ("batch",)), bhd)
     tbl = jnp.clip(table.astype(jnp.int32), 0, nb - 1)
     k = k_pool[tbl].reshape(B, maxb * bs, Hkv, D)
     v = v_pool[tbl].reshape(B, maxb * bs, Hkv, D)
@@ -378,7 +507,7 @@ def set_ssm_xla_impl(name: str) -> None:
 
 def selective_scan(x, dt, A, Bm, C, D, h0=None, *,
                    backend: Optional[str] = None):
-    impl = _pick(backend)
+    impl = _pick(backend, tpu_kernel=False)
     if impl in ("pallas", "interpret"):
         from repro.kernels import selective_scan as sk
         return sk.selective_scan_pallas(x, dt, A, Bm, C, D, h0,
@@ -457,7 +586,7 @@ def selective_scan_step(x, dt, A, Bm, C, D, h):
 
 def rglru(x, r_gate, i_gate, a_param, h0=None, *, c: float = 8.0,
           backend: Optional[str] = None):
-    impl = _pick(backend)
+    impl = _pick(backend, tpu_kernel=False)
     if impl in ("pallas", "interpret"):
         from repro.kernels import rglru_scan as rk
         return rk.rglru_pallas(x, r_gate, i_gate, a_param, h0, c=c,
@@ -538,11 +667,26 @@ def _lora_vjp(impl, scale, x, w, a, b, bias):
     return _lora_forward(impl, scale, x, w, a, b, bias)
 
 
-def _lora_forward(impl, scale, x, w, a, b, bias):
+def _lora_forward(impl, scale, x, w, a, b, bias, contract_split=False):
+    """Under a mesh the kernel splits w's output columns over the tensor-
+    parallel axes; ``contract_split`` splits its contracted rows instead
+    and sums the partial products (the backward's ``dy @ w^T``, where dy
+    and w arrive split along that dim)."""
     if impl in ("pallas", "interpret") and x.ndim == 2:
         from repro.kernels import lora_matmul as lk
-        return lk.lora_matmul_pallas(x, w, a, b, scale, bias,
-                                     interpret=(impl == "interpret"))
+        interp = impl == "interpret"
+        if contract_split:
+            axes = [("batch", "heads"), ("heads", None), ("heads", None),
+                    (None, None)]
+            out, red = ("batch", None), ("heads",)
+        else:
+            axes = [("batch", None), (None, "heads"), (None, None),
+                    (None, "heads")]
+            out, red = ("batch", "heads"), ()
+        ops_ = (x, w, a, b) + (() if bias is None else (bias,))
+        return _on_mesh(lambda *o: lk.lora_matmul_pallas(
+            *o[:4], scale, *o[4:], interpret=interp),
+            ops_, axes + [("heads",)][:len(ops_) - 4], out, red)
     return _lora_xla(x, w, a, b, scale, bias)
 
 
@@ -562,11 +706,16 @@ def _lora_bwd_rule(impl, scale, res, dy):
     x, w, a, b, bias = res
     x2 = x.reshape(-1, x.shape[-1])
     dy2 = dy.reshape(-1, dy.shape[-1])
-    dx = _lora_forward(impl, scale, dy2, w.T, b.T, a.T, None)
+    dx = _lora_forward(impl, scale, dy2, w.T, b.T, a.T, None,
+                       contract_split=True)
     if impl in ("pallas", "interpret"):
         from repro.kernels import lora_matmul as lk
-        da, db = lk.lora_matmul_bwd_pallas(x2, dy2, a, b, scale,
-                                           interpret=(impl == "interpret"))
+        interp = impl == "interpret"
+        da, db = _on_mesh(lambda *o: lk.lora_matmul_bwd_pallas(
+            *o, scale, interpret=interp), (x2, dy2, a, b),
+            (("batch", None), ("batch", "heads"), (None, None),
+             (None, "heads")),
+            [(None, None), (None, "heads")], [("batch", "heads"), ("batch",)])
     else:
         da, db = _lora_bwd_xla(x2, dy2, a, b, scale)
     dw = jax.lax.dot_general(x2, dy2, (((0,), (0,)), ((), ())),
@@ -622,13 +771,18 @@ def lora_bgmv(x, w, a, b, adapter_ids, scale: float = 1.0, bias=None, *,
     if impl in ("pallas", "interpret"):
         from repro.kernels import lora_bgmv as bk
         interp = impl == "interpret"
-        if x.ndim == 3 and x.shape[1] > 1:         # prefill: gathered path
-            return bk.lora_bgmv_seq_pallas(x, w, a, b, ids, float(scale),
-                                           bias, interpret=interp)
+        seq = x.ndim == 3 and x.shape[1] > 1      # prefill: gathered path
+        kern = bk.lora_bgmv_seq_pallas if seq else bk.lora_bgmv_rows_pallas
         shp = x.shape                               # decode rows: BGMV path
-        out = bk.lora_bgmv_rows_pallas(x.reshape(-1, shp[-1]), w, a, b, ids,
-                                       float(scale), bias, interpret=interp)
-        return out.reshape(*shp[:-1], w.shape[-1])
+        xk = x if seq else x.reshape(-1, shp[-1])
+        rows = ("batch",) + (None,) * (xk.ndim - 1)
+        ops_ = (xk, w, a, b, ids) + (() if bias is None else (bias,))
+        axes = [rows, (None, "heads"), (None, None, None),
+                (None, None, "heads"), ("batch",), ("heads",)]
+        out = _on_mesh(lambda *o: kern(*o[:5], float(scale), *o[5:],
+                                       interpret=interp),
+                       ops_, axes[:len(ops_)], rows[:-1] + ("heads",))
+        return out if seq else out.reshape(*shp[:-1], w.shape[-1])
     return _bgmv_xla(x, w, a, b, ids, float(scale), bias)
 
 

@@ -1,26 +1,21 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST precede every other import: jax pins the host
-# device count at first initialization. (REPRO_DRYRUN_DEVICES overrides for
-# the subprocess smoke tests only.)
-if os.environ.get("REPRO_DRYRUN_DEVICES"):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                               + os.environ["REPRO_DRYRUN_DEVICES"])
+"""Multi-pod dry-run: lower + compile every (arch x input-shape) on the
+production mesh, extract memory analysis, cost analysis, roofline terms.
 
-# Multi-pod dry-run: lower + compile every (arch x input-shape) on the
-# production mesh, extract memory analysis, cost analysis, roofline terms.
-#
-# Usage:
-#   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-7b --shape train_4k
-#   PYTHONPATH=src python -m repro.launch.dryrun --all --json results/dryrun.json
-# Flags: --multi-pod (2x16x16 mesh), --json <path>.
-# (No module docstring: the XLA_FLAGS env assignment must be the first
-# statements in the file, before any jax-importing module.)
+Usage:
+  PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-7b --shape train_4k
+  PYTHONPATH=src python -m repro.launch.dryrun --all --json results/dryrun.json
+Flags: --multi-pod (2x16x16 mesh), --json <path>.
 
+``main()`` forces 512 host CPU devices (:func:`force_host_devices`) before
+JAX creates its backend; importing this module leaves the environment
+alone. A library caller that builds its own mesh sets ``XLA_FLAGS`` itself
+before JAX initializes.
+"""
 import argparse
 import dataclasses
 import functools
 import json
+import os
 import sys
 import time
 import traceback
@@ -31,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import INPUT_SHAPES, ModelConfig, get_config, list_configs
 from repro.core import hfsl
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import data_parallel_size, make_production_mesh
 from repro.launch import roofline as rl
 from repro.models import model as M
@@ -258,7 +254,20 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     return result
 
 
+def force_host_devices(n: int = 512) -> None:
+    """Ask XLA's CPU backend for ``n`` host devices (the production mesh).
+
+    Takes effect only before JAX creates its backend, so entry points call
+    it first thing in ``main()``. A device count already in ``XLA_FLAGS``
+    wins."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}".strip())
+
+
 def main(argv=None) -> int:
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default="all")
@@ -266,6 +275,7 @@ def main(argv=None) -> int:
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     archs = ASSIGNED if (args.all or args.arch is None) else [args.arch]
     shapes = list(INPUT_SHAPES) if args.shape == "all" else [args.shape]

@@ -12,6 +12,13 @@ import jax
 from jax.sharding import Mesh
 
 
+def device_summary() -> dict:
+    """``{"platform", "kind", "count"}`` of the devices JAX sees."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
 def _mesh(shape, axes):
     n = int(np.prod(shape))
     devs = jax.devices()

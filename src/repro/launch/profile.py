@@ -1,15 +1,14 @@
-import os
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+"""HLO cost profiler: per-opcode / per-op breakdown of the roofline terms.
 
-# HLO cost profiler: per-opcode / per-op breakdown of the roofline terms.
-# This is the tool behind every EXPERIMENTS.md §Perf iteration — it answers
-# "which op class owns the dominant term?" for a compiled (arch x shape).
-#
-#   PYTHONPATH=src python -m repro.launch.profile --arch qwen2-7b \
-#       --shape train_4k --top 20
-# (Module doc as comment: XLA_FLAGS must precede jax imports.)
+This is the tool behind every EXPERIMENTS.md §Perf iteration — it answers
+"which op class owns the dominant term?" for a compiled (arch x shape).
 
+  PYTHONPATH=src python -m repro.launch.profile --arch qwen2-7b \
+      --shape train_4k --top 20
+
+Like the dry-run, ``main()`` forces 512 host devices before JAX starts;
+importing this module changes nothing.
+"""
 import argparse
 from collections import defaultdict
 
@@ -62,6 +61,8 @@ def profile_hlo(text: str):
 
 
 def main(argv=None):
+    from repro.launch.dryrun import build_lowered, force_host_devices
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)
@@ -69,8 +70,9 @@ def main(argv=None):
     ap.add_argument("--top", type=int, default=15)
     ap.add_argument("--hlo-out", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
-    from repro.launch.dryrun import build_lowered
     lowered, meta = build_lowered(args.arch, args.shape,
                                   multi_pod=args.multi_pod)
     txt = lowered.compile().as_text()

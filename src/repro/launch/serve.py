@@ -42,6 +42,8 @@ import numpy as np
 from repro.checkpoint import io as ckpt_io
 from repro.configs.base import get_config
 from repro.core import telemetry
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import device_summary
 from repro.models import model as M
 
 
@@ -85,6 +87,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="vit-edge")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep only this many layers at the published widths "
+                         "(the depth that fits one chip)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=8)
@@ -102,6 +107,7 @@ def main(argv=None):
                     help="enable telemetry and write the counter/histogram "
                          "snapshot as JSON here")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     traced = args.trace_out or args.metrics_out
     if traced:
@@ -110,6 +116,12 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers:
+        cfg = cfg.with_depth(args.layers)
+    dev = device_summary()
+    print(f"[serve] {cfg.name}: {cfg.n_layers} layers (published "
+          f"{get_config(args.arch).n_layers}) on {dev['platform']} "
+          f"{dev['kind']} x{dev['count']}")
     key = jax.random.PRNGKey(args.seed)
     params = M.init(cfg, key)
     if args.adapters:

@@ -30,6 +30,8 @@ from repro.core.peft import trainable_fraction, tree_bytes
 from repro.data.noniid import partition_by_classes
 from repro.data.pipeline import BatchBank, cluster_batches
 from repro.data.synthetic import ClassificationTask, LMStream
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import device_summary
 from repro.models import model as M
 from repro.optim.optimizers import adamw
 from repro.optim.schedules import warmup_cosine
@@ -39,6 +41,8 @@ def build_cfg(args):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers:
+        cfg = cfg.with_depth(args.layers)
     if args.task == "classify" and not cfg.peft.head_dim_out:
         cfg = cfg.with_(peft=dataclasses.replace(cfg.peft,
                                                  head_dim_out=args.classes))
@@ -49,6 +53,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="vit-edge")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep only this many layers at the published widths "
+                         "(the depth that fits one chip)")
     ap.add_argument("--task", choices=("lm", "classify"), default="classify")
     ap.add_argument("--clusters", type=int, default=4)
     ap.add_argument("--classes", type=int, default=5)
@@ -77,11 +84,16 @@ def main(argv=None):
                     help="enable telemetry and write the counter/histogram "
                          "snapshot as JSON here")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.trace_out or args.metrics_out:
         telemetry.enable()
 
     cfg = build_cfg(args)
+    dev = device_summary()
+    print(f"[train] {cfg.name}: {cfg.n_layers} layers (published "
+          f"{get_config(args.arch).n_layers}) on {dev['platform']} "
+          f"{dev['kind']} x{dev['count']}")
     key = jax.random.PRNGKey(args.seed)
     opt = adamw(warmup_cosine(args.lr, args.steps // 10 + 1, args.steps))
 

@@ -60,8 +60,10 @@ def model_spec(cfg: ModelConfig) -> dict:
     return {"backbone": backbone_spec(cfg), "adapters": adapter_spec(cfg)}
 
 
-def init(cfg: ModelConfig, key: jax.Array) -> dict:
-    return init_from_spec(key, model_spec(cfg))
+def init(cfg: ModelConfig, key: jax.Array, shardings=None) -> dict:
+    """Seeded parameters; ``shardings`` (a tree matching
+    :func:`model_spec`) places each leaf as soon as it is drawn."""
+    return init_from_spec(key, model_spec(cfg), shardings)
 
 
 def cache_spec(cfg: ModelConfig, batch: int, seq_len: int, *,

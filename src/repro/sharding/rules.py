@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 from typing import Any, Callable, Optional, Sequence
 
@@ -176,6 +177,12 @@ def _get() -> tuple[Optional[Mesh], Optional[dict]]:
     return getattr(_ctx, "mesh", None), getattr(_ctx, "rules", None)
 
 
+def active_rules() -> tuple[Optional[Mesh], Optional[dict]]:
+    """(mesh, rules) of the innermost :func:`use_rules` context
+    ((None, None) outside)."""
+    return _get()
+
+
 @contextlib.contextmanager
 def use_rules(mesh: Optional[Mesh], rules: Optional[dict] = None):
     """Activate (mesh, rules) for `shard()` constraints inside model code."""
@@ -251,24 +258,46 @@ def _is_spec(x) -> bool:
     return isinstance(x, ParamSpec)
 
 
-def init_from_spec(key: jax.Array, tree) -> Any:
-    """Materialize a ParamSpec tree into initialized arrays."""
+def _draw(key: jax.Array, s: ParamSpec) -> jax.Array:
+    if s.init == "zeros":
+        return jnp.zeros(s.shape, s.dtype)
+    if s.init == "ones":
+        return jnp.ones(s.shape, s.dtype)
+    w = jax.random.normal(key, s.shape, jnp.float32)
+    if s.init == "scaled":                # fan-in scaled normal
+        fan_in = s.shape[-2] if len(s.shape) >= 2 else max(s.shape[-1], 1)
+        return (w / np.sqrt(fan_in)).astype(s.dtype)
+    return (w * s.scale).astype(s.dtype)
+
+
+# tracelint: keys=specs,shardings
+@functools.lru_cache(maxsize=64)
+def _tree_drawer(specs: tuple, shardings: Optional[tuple]):
+    """ONE jitted program that draws every leaf in its final dtype.
+
+    Each leaf's f32 draw fuses into its cast, so the device holds only the
+    final tree (an eager f32 draw of one stacked weight at published
+    widths is several GB on top of the model), and one compile serves the
+    whole model. With ``shardings`` every device draws just its shards."""
+
+    def draw_tree(keys):
+        return tuple(_draw(keys[i], s) for i, s in enumerate(specs))
+
+    return jax.jit(draw_tree, out_shardings=shardings)
+
+
+def init_from_spec(key: jax.Array, tree, shardings=None) -> Any:
+    """Materialize a ParamSpec tree into initialized arrays.
+
+    ``shardings`` (a matching tree of shardings, e.g. from
+    :func:`named_shardings`) places each leaf where it is drawn, so no
+    device ever holds the whole unplaced tree. Values do not depend on the
+    placement (partitionable threefry)."""
     leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
-    out = []
-    for k, s in zip(keys, leaves):
-        if s.init == "zeros":
-            out.append(jnp.zeros(s.shape, s.dtype))
-        elif s.init == "ones":
-            out.append(jnp.ones(s.shape, s.dtype))
-        elif s.init == "scaled":  # fan-in scaled normal
-            fan_in = s.shape[-2] if len(s.shape) >= 2 else max(s.shape[-1], 1)
-            w = jax.random.normal(k, s.shape, jnp.float32) / np.sqrt(fan_in)
-            out.append(w.astype(s.dtype))
-        else:
-            w = jax.random.normal(k, s.shape, jnp.float32) * s.scale
-            out.append(w.astype(s.dtype))
-    return jax.tree.unflatten(treedef, out)
+    places = None if shardings is None else tuple(jax.tree.leaves(shardings))
+    return jax.tree.unflatten(treedef,
+                              _tree_drawer(tuple(leaves), places)(keys))
 
 
 def shape_structs(tree) -> Any:

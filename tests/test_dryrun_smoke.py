@@ -26,7 +26,7 @@ CASES = [
 def test_dryrun_reduced_subprocess(arch, shape):
     script = textwrap.dedent(f"""
         import os
-        os.environ["REPRO_DRYRUN_DEVICES"] = "16"
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
         import sys; sys.path.insert(0, "src")
         from repro.launch import dryrun
         from repro.launch import mesh as mesh_lib
@@ -47,6 +47,8 @@ def test_dryrun_reduced_subprocess(arch, shape):
         print("DRYRUN_OK", roof.bottleneck, f"{{costs.flops:.2e}}")
     """)
     r = subprocess.run([sys.executable, "-c", script], cwd=ROOT,
+                       # a CPU child (forced host devices): never the chip
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
                        capture_output=True, text=True, timeout=900)
     assert "DRYRUN_OK" in r.stdout, (r.stdout[-2000:] + r.stderr[-3000:])
 

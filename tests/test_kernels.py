@@ -370,3 +370,55 @@ def test_paged_flash_decode_bit_parity_with_dense(backend):
     else:
         np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
                                    **tol(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# Backend selection and the training path through the attention kernel
+# ---------------------------------------------------------------------------
+
+def test_backend_follows_platform_and_pallas_needs_tpu():
+    """Off TPU the default is xla; choosing pallas raises (no quiet
+    fallback), globally and per call."""
+    assert jax.default_backend() != "tpu"
+    assert ops.get_backend() == "xla"
+    with pytest.raises(ValueError, match="needs a TPU"):
+        ops.set_backend("pallas")
+    assert ops.get_backend() == "xla"
+    q = jnp.ones((1, 8, 1, 8))
+    with pytest.raises(ValueError, match="needs a TPU"):
+        ops.flash_attention(q, q, q, q_pos=jnp.arange(8),
+                            kv_pos=jnp.arange(8), backend="pallas")
+
+
+def test_flash_attention_kernel_grad_is_the_xla_grad():
+    """The kernel's custom VJP differentiates the blocked XLA algorithm at
+    the same inputs, so gradients equal the xla backend's exactly."""
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (2, 24, 4, 16))
+    k = jax.random.normal(ks[1], (2, 28, 2, 16))
+    v = jax.random.normal(ks[2], (2, 28, 2, 16))
+    w = jax.random.normal(ks[3], (2, 24, 4, 16))
+    qp, kp = jnp.arange(24), jnp.arange(28) - 4
+
+    def loss(backend):
+        return lambda q, k, v: jnp.vdot(w, ops.flash_attention(
+            q, k, v, q_pos=qp, kv_pos=kp, block_q=8, block_kv=8,
+            backend=backend))
+
+    want = jax.grad(loss("xla"), argnums=(0, 1, 2))(q, k, v)
+    got = jax.grad(loss("interpret"), argnums=(0, 1, 2))(q, k, v)
+    for g, h in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(h))
+
+
+@pytest.mark.parametrize("S,block_s", [(20, 8), (33, 16)])
+def test_lora_bgmv_seq_tiles_the_sequence(S, block_s):
+    """Prompts longer than one sequence tile run over an S grid dim."""
+    from repro.kernels.lora_bgmv import lora_bgmv_seq_pallas
+    x, w, a, b, bias, ids = _bgmv_operands(2, 64, 48, 4, 3, jnp.float32,
+                                           True, seq=S)
+    want = ref.lora_bgmv(x, w, a, b, ids, 2.0, bias)
+    got = lora_bgmv_seq_pallas(x, w, a, b, ids, 2.0, bias, block_s=block_s,
+                               interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **tol(jnp.float32))

@@ -12,6 +12,7 @@ Two layers of coverage:
   slot dim placed on `data` (asserted from the live array shardings and
   via jax.debug.visualize_array_sharding).
 """
+import ast
 import os
 import subprocess
 import sys
@@ -298,6 +299,8 @@ _MESH_SCRIPT = textwrap.dedent("""
 @pytest.fixture(scope="module")
 def mesh_parity_run():
     r = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], cwd=ROOT,
+                       # a CPU child (forced host devices): never the chip
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
                        capture_output=True, text=True, timeout=900)
     return r
 
@@ -340,3 +343,216 @@ def test_default_suite_excludes_slow_marker():
         txt = f.read()
     assert "not slow" in txt and "addopts" in txt
     assert "slow:" in txt                     # marker stays registered
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels under a mesh (subprocess: forced host devices)
+# ---------------------------------------------------------------------------
+
+_KERNEL_MESH_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys; sys.path.insert(0, "src")
+    import numpy as np
+    import jax
+    jax.config.update("jax_default_matmul_precision", "highest")
+
+    from repro.configs.base import get_config
+    from repro.core import hfsl
+    from repro.core.adapter_bank import AdapterBank
+    from repro.kernels import ops
+    from repro.launch.engine import DecodeEngine
+    from repro.launch.mesh import make_test_mesh
+    from repro.models import model as M
+    from repro.optim.optimizers import adamw
+    from repro.sharding import rules as R
+
+    mesh = make_test_mesh(2, 2)
+    cfg = get_config("qwen2-7b").reduced().with_(dtype="float32",
+                                                 vocab_size=64)
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    adapters = {d: M.init(cfg, ks[i])["adapters"]
+                for i, d in enumerate(["d0", "d1"])}
+    backbone = M.init(cfg, ks[-1])["backbone"]
+    # init placed leaf by leaf on the mesh draws the same values
+    placed = M.init(cfg, ks[-1], shardings=R.named_shardings(
+        M.model_spec(cfg), mesh, R.serving_rules()))["backbone"]
+    for a, b in zip(jax.tree.leaves(placed), jax.tree.leaves(backbone)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert len(jax.tree.leaves(placed)[0].sharding.device_set) == 4
+    rng = np.random.default_rng(0)
+    reqs = [(rng.integers(0, 64, size=n), ["d0", "d1"][i % 2], 4)
+            for i, n in enumerate([5, 9, 12, 7, 3, 10])]
+
+    with ops.backend("interpret"):
+        # the kernels run inside a replicated shard_map under the mesh:
+        # multi-tenant drain (flash_attention, flash_decode, lora_bgmv)
+        outs = []
+        for m in (None, mesh):
+            bank = AdapterBank.create(adapters, mesh=m)
+            bb = backbone if m is None else \\
+                M.place_params({"backbone": backbone}, cfg, m)["backbone"]
+            eng = DecodeEngine(cfg, slots=4, bank=bank, mesh=m)
+            uids = [eng.submit(t, g, domain=d) for t, d, g in reqs]
+            comps, _ = eng.run(bank.serving_params(bb))
+            by = {c.uid: c.tokens for c in comps}
+            outs.append(np.stack([by[u] for u in uids]))
+        np.testing.assert_array_equal(outs[0], outs[1])
+        print("KERNEL_DRAIN_OK")
+
+        # HFSL round through the attention and LoRA kernels' VJPs
+        C, opt = 2, adamw(1e-2)
+        toks = rng.integers(0, 64, size=(1, C, 2, 9))
+        bank = {"tokens": toks[..., :-1], "labels": toks[..., 1:]}
+        state = hfsl.init_hfsl_state(None, cfg, C, opt, lambda c, k: {
+            "backbone": backbone, "adapters": adapters["d0"]})
+        losses = []
+        for m in (None, mesh):
+            kw = {}
+            st = state
+            if m is not None:
+                rules = R.hfsl_round_rules(cfg.family)
+                spec = hfsl.hfsl_state_spec(cfg, C, opt, M.model_spec)
+                st = jax.device_put(state, R.named_shardings(spec, m, rules))
+                kw = dict(mesh=m, rules=rules, state_spec=spec)
+            rnd = hfsl.make_hfsl_round(cfg, opt, M.lm_loss, steps=3,
+                                       sync_every=2, **kw)
+            _, met = rnd(st, bank, 0)
+            losses.append(np.asarray(met["loss"]))
+        np.testing.assert_allclose(losses[1], losses[0], rtol=2e-5)
+        print("KERNEL_ROUND_OK")
+""")
+
+
+def test_pallas_kernels_under_a_mesh_match_unsharded():
+    """Engine drain and HFSL round with the interpret-mode Pallas kernels
+    on a 2x2 host mesh equal the unsharded run."""
+    r = subprocess.run([sys.executable, "-c", _KERNEL_MESH_SCRIPT], cwd=ROOT,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                       capture_output=True, text=True, timeout=900)
+    assert "KERNEL_DRAIN_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-3000:]
+    assert "KERNEL_ROUND_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-3000:]
+
+
+# ---------------------------------------------------------------------------
+# Kernel blocks under a mesh: which dims each device holds a slice of
+# ---------------------------------------------------------------------------
+
+_KERNEL_SPLIT_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys; sys.path.insert(0, "src")
+    import jax, jax.numpy as jnp, numpy as np
+    jax.config.update("jax_default_matmul_precision", "highest")
+    from repro.kernels import ops
+    from repro.launch.mesh import make_test_mesh
+    from repro.sharding import rules as R
+
+    mesh = make_test_mesh(2, 2)
+    seen = []
+    real = jax.shard_map
+
+    def recording(f, **kw):
+        seen.append(kw["in_specs"] + (kw["out_specs"],))
+        return real(f, **kw)
+
+    jax.shard_map = recording
+
+    def run(name, fn, *args):
+        ref = fn(*args)
+
+        def on_mesh(*a):
+            with R.use_rules(mesh, R.serving_rules()):
+                return fn(*a)
+        seen.clear()
+        out = jax.jit(on_mesh)(*args)
+        for x, y in zip(jax.tree.leaves(ref), jax.tree.leaves(out)):
+            np.testing.assert_allclose(np.asarray(y), np.asarray(x),
+                                       rtol=1e-5, atol=1e-5)
+        def plain(s):                 # PartitionSpec(s) -> tuples
+            return (tuple(s) if isinstance(s, jax.sharding.PartitionSpec)
+                    else tuple(plain(x) for x in s))
+        print("SPLIT", name, repr([[plain(s) for s in call]
+                                    for call in seen]))
+
+    k = jax.random.split(jax.random.PRNGKey(0), 8)
+    q = jax.random.normal(k[0], (2, 16, 4, 64))
+    kv = jax.random.normal(k[1], (2, 16, 2, 64))
+    pos = jnp.arange(16)
+    run("attention", lambda q, kv: ops.flash_attention(
+        q, kv, 2 * kv, q_pos=pos, kv_pos=pos, backend="interpret"), q, kv)
+    run("attention_one_kv_head", lambda q, kv: ops.flash_attention(
+        q, kv[:, :, :1], kv[:, :, 1:], q_pos=pos, kv_pos=pos,
+        backend="interpret"), q, kv)
+    run("decode", lambda q, kv: ops.flash_decode(
+        q[:, 0], kv, 2 * kv, q_pos=jnp.array([5, 9]), kv_pos=pos,
+        backend="interpret"), q, kv)
+    pool = jax.random.normal(k[3], (6, 4, 2, 64))
+    tbl = jnp.array([[0, 2, 4, 6], [1, 3, 5, 6]], jnp.int32)
+    run("paged", lambda q, p: ops.flash_decode_paged(
+        q[:, 0], p, 2 * p, tbl, q_pos=jnp.array([9, 12]),
+        backend="interpret"), q, pool)
+    x, w = jax.random.normal(k[5], (8, 32)), jax.random.normal(k[6], (32, 64))
+    a, b = jax.random.normal(k[7], (32, 4)), jax.random.normal(k[2], (4, 64))
+    bias = jnp.arange(64.0)
+    run("lora_grad", jax.grad(lambda x, a, b: jnp.sum(jnp.sin(
+        ops.lora_matmul(x, w, a, b, 2.0, bias, backend="interpret"))),
+        argnums=(0, 1, 2)), x, a, b)
+    A = jax.random.normal(k[3], (3, 32, 4))
+    B = jax.random.normal(k[4], (3, 4, 64))
+    ids = jnp.array([0, 2, 1, 1, 0, 2, 2, 1])
+    run("bgmv_rows", lambda x: ops.lora_bgmv(
+        x, w, A, B, ids, 2.0, bias, backend="interpret"), x)
+    run("bgmv_seq", lambda x: ops.lora_bgmv(
+        x.reshape(2, 4, 32), w, A, B, ids[:2], 2.0, backend="interpret"), x)
+""")
+
+_D, _M = "data", "model"
+_BSHD = (_D, None, _M, None)
+_BHD = (_D, _M, None)
+_POOL = (None, None, _M, None)
+_KERNEL_SPLITS = {
+    # q, k, v split by sequence over `data` and by head over `model`
+    "attention": [[_BSHD, _BSHD, _BSHD, (None,), (None,), _BSHD]],
+    # one KV head cannot split over two model devices: heads stay whole
+    "attention_one_kv_head": [[(_D, None, None, None)] * 3
+                              + [(None,), (None,), (_D, None, None, None)]],
+    "decode": [[_BHD, _BSHD, _BSHD, (_D,), (_D, None), _BHD]],
+    # any row's table may name any block: the pool splits by head only
+    "paged": [[_BHD, _POOL, _POOL, (_D, None), (_D,), _BHD]],
+    # forward: output columns over `model`; dx contracts the split columns
+    # (partials summed); dA / dB sum over rows and columns as they contract
+    "lora_grad": [[(_D, None), (None, _M), (None, None), (None, _M), (_M,),
+                   (_D, _M)],
+                  [(_D, _M), (_M, None), (_M, None), (None, None),
+                   (_D, None)],
+                  [(_D, None), (_D, _M), (None, None), (None, _M),
+                   ((None, None), (None, _M))]],
+    "bgmv_rows": [[(_D, None), (None, _M), (None, None, None),
+                   (None, None, _M), (_D,), (_M,), (_D, _M)]],
+    "bgmv_seq": [[(_D, None, None), (None, _M), (None, None, None),
+                  (None, None, _M), (_D,), (_D, None, _M)]],
+}
+
+
+@pytest.fixture(scope="module")
+def kernel_split_run():
+    r = subprocess.run([sys.executable, "-c", _KERNEL_SPLIT_SCRIPT], cwd=ROOT,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                       capture_output=True, text=True, timeout=600)
+    got = {}
+    for line in r.stdout.splitlines():
+        if line.startswith("SPLIT "):
+            _, name, specs = line.split(" ", 2)
+            got[name] = specs
+    return got, r.stdout[-2000:] + r.stderr[-3000:]
+
+
+@pytest.mark.parametrize("name", sorted(_KERNEL_SPLITS))
+def test_kernel_blocks_split_over_the_mesh(kernel_split_run, name):
+    """Under a 2x2 (`data`, `model`) mesh each Pallas kernel runs on its
+    device's block — rows over `data`, heads / projection columns over
+    `model` where they divide — and equals the call without a mesh."""
+    got, log = kernel_split_run
+    assert name in got, log
+    assert ast.literal_eval(got[name]) == _KERNEL_SPLITS[name]

@@ -56,7 +56,8 @@ class TestValidation:
         import jax.numpy as jnp
         from repro.core.sl_pipeline import pipeline_classify
         cfg = get_config("vit-edge")
-        mesh = jax.make_mesh((1,), ("stage",))
+        mesh = jax.make_mesh((1,), ("stage",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         toks = jnp.zeros((5, 8), jnp.int32)     # B=5 not divisible by M=4
         with pytest.raises(ValueError, match="n_microbatches"):
             pipeline_classify({}, {}, toks, cfg, mesh, n_microbatches=4)
@@ -77,7 +78,8 @@ def test_pipeline_matches_monolithic_subprocess():
         cfg = get_config("vit-edge").reduced().with_(n_layers=4, dtype="float32")
         cfg = cfg.with_(peft=dataclasses.replace(cfg.peft, head_dim_out=5))
         params = M.init(cfg, jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = jax.make_mesh((4,), ("stage",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         st = split_for_stages(params, cfg, 4)
         toks = jax.random.randint(jax.random.PRNGKey(1), (16, 24), 0,
                                   cfg.vocab_size)
@@ -101,5 +103,7 @@ def test_pipeline_matches_monolithic_subprocess():
         print("PIPELINE_OK", err)
     """)
     r = subprocess.run([sys.executable, "-c", script], cwd=ROOT,
+                       # a CPU child (forced host devices): never the chip
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
                        capture_output=True, text=True, timeout=900)
     assert "PIPELINE_OK" in r.stdout, r.stdout + r.stderr
