@@ -93,7 +93,9 @@ REF_ROWS = 4                     # rows per reference forward
 # none lies more than REF_GAP row standard deviations below its maximum.
 REF_AGREE, REF_GAP = 0.9, 0.25
 IR_DIR = ROOT / ".smoke_ir"      # lowered programs, read back for kernels
-FUSED = ("jit_impl", "jit_round_core")   # engine dispatches, HFSL round
+FUSED = ("jit_wave_prefill", "jit_refill", "jit_decode_segment",   # engine
+         "jit_paged_prefill", "jit_paged_refill", "jit_paged_suffix",
+         "jit_hfsl_round")                                # HFSL round
 
 
 # ---------------------------------------------------------------------------

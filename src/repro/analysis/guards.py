@@ -18,7 +18,8 @@ showing up as a latency mystery in production traces.
     with compile_guard(max_compiles=0):        # warm path: no compiles
         engine.run(params)
 
-    with compile_guard(max_compiles=12, match=r"impl") as log:
+    with compile_guard(max_compiles=12,
+                       match=r"prefill|refill|decode_segment") as log:
         first_drain()                          # fused fns only
     print(log.count, log.names)
 """
@@ -86,9 +87,10 @@ def compile_guard(max_compiles: Optional[int] = None, *,
       block compiles more than N programs. ``max_compiles=0`` is the
       strongest form: the block must run entirely off warm jit caches.
     - ``match`` restricts counting to compiled-function names matching
-      the regex (the repo's fused serving/training dispatches are all
-      named ``impl``/``round_core``, so ``match=r"impl"`` isolates them
-      from one-off convert/broadcast micro-compiles).
+      the regex (the repo's fused serving/training dispatches are named
+      for what they run — ``wave_prefill``, ``refill``,
+      ``decode_segment``, ``hfsl_round`` ... — so such a regex isolates
+      them from one-off convert/broadcast micro-compiles).
     - counts are exported to ``tel`` (default: the global telemetry
       registry) as counter ``analysis.compiles`` plus
       ``analysis.compile_guard_trips`` on budget violations.

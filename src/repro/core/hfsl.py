@@ -176,10 +176,11 @@ def _make_cluster_update(cfg, optimizer: Optimizer, loss_fn: Callable,
             grads = jax.tree.map(lambda g: (g * inv).astype(g.dtype), gs)
             loss = ls * inv
             aux = jax.tree.map(lambda v: v * inv, axs)
-        if clip_norm:
-            grads, _ = clip_by_global_norm(grads, clip_norm)
-        updates, opt_state = optimizer.update(grads, opt_state, adapters)
-        adapters = apply_updates(adapters, updates)
+        with jax.named_scope("optimizer"):
+            if clip_norm:
+                grads, _ = clip_by_global_norm(grads, clip_norm)
+            updates, opt_state = optimizer.update(grads, opt_state, adapters)
+            adapters = apply_updates(adapters, updates)
         return adapters, opt_state, loss, aux
 
     return one_cluster
@@ -218,6 +219,7 @@ def _clusters_finite(tree) -> jax.Array:
     return functools.reduce(jnp.logical_and, oks)
 
 
+@jax.named_scope("fedavg")
 def _sync_at_boundary(adapters_c, new_step, *, sync_every: int,
                       always_sync: bool, mask=None):
     """FedAvg at ``sync_every`` multiples of the (possibly traced) counter.
@@ -425,7 +427,7 @@ def make_hfsl_round(cfg, optimizer: Optimizer, loss_fn: Callable, *,
                                microbatches=microbatches,
                                spmd_axes=spmd_axes, faulted=faulted)
 
-        def round_core(train: dict, backbone, bank: dict, offset,
+        def hfsl_round(train: dict, backbone, bank: dict, offset,
                        mask=None, corrupt=None) -> tuple[dict, dict]:
             epoch = jax.tree.leaves(bank)[0].shape[0]
             off = jnp.asarray(offset, jnp.int32)
@@ -442,13 +444,13 @@ def make_hfsl_round(cfg, optimizer: Optimizer, loss_fn: Callable, *,
                                     jnp.arange(steps, dtype=jnp.int32))
 
         if not jit:
-            return round_core
+            return hfsl_round
         # donate only the train state (argnum 0): the backbone rides as its
         # own argument precisely so it is excluded from donation — callers
         # keep serving from the same frozen backbone buffers.
         donate_argnums = (0,) if donate else ()
         if mesh is None:
-            return jax.jit(round_core, donate_argnums=donate_argnums)
+            return jax.jit(hfsl_round, donate_argnums=donate_argnums)
         state_sh = named_shardings(state_spec, mesh, rules)
         train_sh = {k: state_sh[k] for k in _TRAIN_KEYS}
         # the bank in_sharding is a pytree prefix: one sharding covers
@@ -458,7 +460,7 @@ def make_hfsl_round(cfg, optimizer: Optimizer, loss_fn: Callable, *,
                                rules=rules)
         in_sh = (train_sh, state_sh["backbone"], bank_sh, None) \
             + ((None, None) if faulted else ())
-        return jax.jit(round_core, in_shardings=in_sh,
+        return jax.jit(hfsl_round, in_shardings=in_sh,
                        out_shardings=(train_sh, None),
                        donate_argnums=donate_argnums)
 

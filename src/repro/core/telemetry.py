@@ -18,7 +18,10 @@ reports through:
 - **Spans** — ``with tel.span("decode_segment", wave=3, rows=8):`` records
   a named interval on the monotonic clock (`time.perf_counter`), with
   nesting depth tracked per thread. ``span(...) as sp`` allows late
-  attributes (``sp.set(tokens=n)``) for values only known at exit.
+  attributes (``sp.set(tokens=n)``) for values only known at exit. The
+  same span also enters a ``jax.profiler.TraceAnnotation`` of that name
+  and those arguments, so inside ``jax.profiler.trace`` it lands on the
+  profile's host plane, on the clock of the device ops it dispatched.
 - **Export** — :meth:`Telemetry.export_trace` writes Chrome trace-event
   JSON (open in Perfetto / chrome://tracing: one timeline row per thread,
   spans nested by enclosure), :meth:`Telemetry.snapshot` returns a plain
@@ -35,7 +38,8 @@ indistinguishable from no instrumentation at all.
 
 Host-side only by design: spans bracket *dispatches* (what the host asked
 the device to do and when the result synced), not on-device kernel time —
-that is what roofline/profile tooling is for. Not thread-safe for
+the device ops they dispatch sit beside them in the same profile, named
+by the programs' named scopes. Not thread-safe for
 concurrent writers beyond CPython atomicity; the engines are host-serial.
 """
 from __future__ import annotations
@@ -149,8 +153,11 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    """Live span handle; records itself into the owning Telemetry on exit."""
-    __slots__ = ("_tel", "name", "args", "_t0", "_depth")
+    """Live span handle; records itself into the owning Telemetry on exit.
+
+    It also holds a profiler annotation of the same name and the arguments
+    given at creation (late ``set`` attributes reach only the record)."""
+    __slots__ = ("_tel", "name", "args", "_t0", "_depth", "_anno")
 
     def __init__(self, tel: "Telemetry", name: str, args: dict) -> None:
         self._tel = tel
@@ -162,6 +169,9 @@ class _Span:
         self.args.update(args)
 
     def __enter__(self) -> "_Span":
+        import jax.profiler              # lazy: telemetry imports no jax
+        self._anno = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._anno.__enter__()
         local = self._tel._local
         self._depth = getattr(local, "depth", 0)
         local.depth = self._depth + 1
@@ -170,6 +180,7 @@ class _Span:
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        self._anno.__exit__(*exc)
         tel = self._tel
         tel._local.depth = self._depth
         tel.spans.append(SpanRecord(

@@ -103,19 +103,23 @@ def flash_attention_pallas(q, k, v, *, q_pos, kv_pos, window: int = 0,
     scale = scale if scale is not None else D ** -0.5
     bq, bkv = min(block_q, S), min(block_kv, T)
 
-    # Pad: seq dims to block multiples, head_dim to the 128-lane MXU width,
-    # then flatten (heads, Dp) so each head is a lane-aligned column slab.
-    Dp = max(128, D + (-D) % 128)
-    qp = _pad(_pad(q, 1, bq), 3, Dp)
-    kp = _pad(_pad(k, 1, bkv), 3, Dp)
-    vp = _pad(_pad(v, 1, bkv), 3, Dp)
-    Sp, Tp = qp.shape[1], kp.shape[1]
-    nq, nk = Sp // bq, Tp // bkv
-    qp = qp.reshape(B, Sp, Hq * Dp)
-    kp = kp.reshape(B, Tp, Hkv * Dp)
-    vp = vp.reshape(B, Tp, Hkv * Dp)
-    qpos = _pad(q_pos.astype(jnp.int32), 0, bq, value=-(10 ** 9))[:, None]
-    kpos = _pad(kv_pos.astype(jnp.int32), 0, bkv, value=10 ** 9)[None, :]
+    with jax.named_scope("kv_cache"):
+        # Pad: seq dims to block multiples, head_dim to the 128-lane MXU
+        # width, then flatten (heads, Dp) so each head is a lane-aligned
+        # column slab.
+        Dp = max(128, D + (-D) % 128)
+        qp = _pad(_pad(q, 1, bq), 3, Dp)
+        kp = _pad(_pad(k, 1, bkv), 3, Dp)
+        vp = _pad(_pad(v, 1, bkv), 3, Dp)
+        Sp, Tp = qp.shape[1], kp.shape[1]
+        nq, nk = Sp // bq, Tp // bkv
+        qp = qp.reshape(B, Sp, Hq * Dp)
+        kp = kp.reshape(B, Tp, Hkv * Dp)
+        vp = vp.reshape(B, Tp, Hkv * Dp)
+        qpos = _pad(q_pos.astype(jnp.int32), 0, bq,
+                    value=-(10 ** 9))[:, None]
+        kpos = _pad(kv_pos.astype(jnp.int32), 0, bkv,
+                    value=10 ** 9)[None, :]
 
     grid = (B, Hq, nq, nk)
     out = pl.pallas_call(
@@ -137,5 +141,6 @@ def flash_attention_pallas(q, k, v, *, q_pos, kv_pos, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qpos, kpos, qp, kp, vp)
     return out.reshape(B, Sp, Hq, Dp)[:, :S, :, :D]

@@ -176,12 +176,13 @@ def flash_decode_paged_pallas(q, k_pool, v_pool, table, *, q_pos,
     maxb = table.shape[1]
     scale = scale if scale is not None else D ** -0.5
 
-    Dp = max(128, D + (-D) % 128)
-    qp4 = _pad(q.reshape(B, Hkv, g, D), 3, Dp)
-    kp = _pad(k_pool, 3, Dp).reshape(nb, bs, Hkv * Dp)
-    vp = _pad(v_pool, 3, Dp).reshape(nb, bs, Hkv * Dp)
-    qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
-    tbl = jnp.clip(table.astype(jnp.int32), 0, nb - 1)
+    with jax.named_scope("kv_cache"):
+        Dp = max(128, D + (-D) % 128)
+        qp4 = _pad(q.reshape(B, Hkv, g, D), 3, Dp)
+        kp = _pad(k_pool, 3, Dp).reshape(nb, bs, Hkv * Dp)
+        vp = _pad(v_pool, 3, Dp).reshape(nb, bs, Hkv * Dp)
+        qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
+        tbl = jnp.clip(table.astype(jnp.int32), 0, nb - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -207,6 +208,7 @@ def flash_decode_paged_pallas(q, k_pool, v_pool, table, *, q_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dp), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
     )(tbl, qpos, qp4, kp, vp)
     return out[..., :D].reshape(B, Hq, D)
 
@@ -224,17 +226,19 @@ def flash_decode_pallas(q, k, v, *, q_pos, kv_pos, window: int = 0,
     scale = scale if scale is not None else D ** -0.5
     bkv = min(block_kv, T)
 
-    Dp = max(128, D + (-D) % 128)
-    qp4 = _pad(q.reshape(B, Hkv, g, D), 3, Dp)
-    kp = _pad(_pad(k, 1, bkv), 3, Dp)
-    vp = _pad(_pad(v, 1, bkv), 3, Dp)
-    Tp = kp.shape[1]
-    nk = Tp // bkv
-    kp = kp.reshape(B, Tp, Hkv * Dp)
-    vp = vp.reshape(B, Tp, Hkv * Dp)
-    qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
-    kvpos = _pad(jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T)),
-                 1, bkv, value=10 ** 9).reshape(B, nk, bkv)  # pad invisible
+    with jax.named_scope("kv_cache"):
+        Dp = max(128, D + (-D) % 128)
+        qp4 = _pad(q.reshape(B, Hkv, g, D), 3, Dp)
+        kp = _pad(_pad(k, 1, bkv), 3, Dp)
+        vp = _pad(_pad(v, 1, bkv), 3, Dp)
+        Tp = kp.shape[1]
+        nk = Tp // bkv
+        kp = kp.reshape(B, Tp, Hkv * Dp)
+        vp = vp.reshape(B, Tp, Hkv * Dp)
+        qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
+        kvpos = _pad(jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32),
+                                      (B, T)),
+                     1, bkv, value=10 ** 9).reshape(B, nk, bkv)  # invisible
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -260,5 +264,6 @@ def flash_decode_pallas(q, k, v, *, q_pos, kv_pos, window: int = 0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dp), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(qpos, kvpos, qp4, kp, vp)
     return out[..., :D].reshape(B, Hq, D)

@@ -129,6 +129,7 @@ def lora_bgmv_rows_pallas(x, w, a, b, adapter_ids, scale: float = 1.0,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, rp), jnp.float32)],
         interpret=interpret,
+        name="lora_bgmv_rows",
     )(idsp, xp, wp, ap, bp, biasp)
     return out[:M, :N]
 
@@ -217,5 +218,6 @@ def lora_bgmv_seq_pallas(x, w, a, b, adapter_ids, scale: float = 1.0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sp, Np), x.dtype),
         interpret=interpret,
+        name="lora_bgmv_seq",
     )(adapter_ids.astype(jnp.int32), xp, wp, ap, bp, biasp)
     return out[:, :S, :N]

@@ -107,6 +107,7 @@ def lora_matmul_pallas(x, w, a, b, scale: float = 1.0,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, rp), jnp.float32)],
         interpret=interpret,
+        name="lora_matmul_fwd",
     )(xp, wp, ap, bp, biasp)
     return out[:M, :N]
 
@@ -178,5 +179,6 @@ def lora_matmul_bwd_pallas(x, dy, a, b, scale: float = 1.0, *,
         out_shape=[jax.ShapeDtypeStruct((Kp, rp), jnp.float32),
                    jax.ShapeDtypeStruct((rp, Np), jnp.float32)],
         interpret=interpret,
+        name="lora_matmul_bwd",
     )(xp, dyp, ap, bp)
     return da[:K, :r], db[:r, :N]

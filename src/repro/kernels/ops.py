@@ -355,18 +355,19 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if impl in ("pallas", "interpret"):
         from repro.kernels import flash_decode as fdk
         B, T = k.shape[0], k.shape[1]
-        if prefix_k is not None:
-            pk, pv = _broadcast_prefix(prefix_k, prefix_v, B)
-            n_p = pk.shape[1]
-            k = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
-            v = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-            kv_pos = jnp.concatenate(
-                [jnp.full((B, n_p), -1, jnp.int32),
-                 jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T))],
-                axis=1)
-        T = k.shape[1]
-        qp = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
-        kp = jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T))
+        with jax.named_scope("kv_cache"):    # the kernel's one K/V bank
+            if prefix_k is not None:
+                pk, pv = _broadcast_prefix(prefix_k, prefix_v, B)
+                n_p = pk.shape[1]
+                k = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
+                v = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
+                kv_pos = jnp.concatenate(
+                    [jnp.full((B, n_p), -1, jnp.int32),
+                     jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32),
+                                      (B, T))], axis=1)
+            T = k.shape[1]
+            qp = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
+            kp = jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T))
         bhd, bthd = ("batch", "heads", None), ("batch", None, "heads", None)
         return _on_mesh(
             lambda q, k, v, qp, kp: fdk.flash_decode_pallas(
@@ -476,9 +477,10 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                 interpret=(impl == "interpret")),
             (q, k_pool, v_pool, table, qp),
             (bhd, pool, pool, ("batch", None), ("batch",)), bhd)
-    tbl = jnp.clip(table.astype(jnp.int32), 0, nb - 1)
-    k = k_pool[tbl].reshape(B, maxb * bs, Hkv, D)
-    v = v_pool[tbl].reshape(B, maxb * bs, Hkv, D)
+    with jax.named_scope("kv_cache"):
+        tbl = jnp.clip(table.astype(jnp.int32), 0, nb - 1)
+        k = k_pool[tbl].reshape(B, maxb * bs, Hkv, D)
+        v = v_pool[tbl].reshape(B, maxb * bs, Hkv, D)
     kv_pos = jnp.arange(maxb * bs, dtype=jnp.int32)
     if impl in ("pallas", "interpret"):           # prefix bank: dense kernel
         return flash_decode(q, k, v, q_pos=q_pos, kv_pos=kv_pos,

@@ -84,5 +84,6 @@ def rglru_pallas(x, r_gate, i_gate, a_param, h0=None, *, c: float = 8.0,
         ],
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
     )(x, r_gate, i_gate, a2, h0)
     return hs, hT

@@ -93,5 +93,6 @@ def selective_scan_pallas(x, dt, A, Bm, C, D, h0=None, *,
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
         interpret=interpret,
+        name="selective_scan",
     )(x, dt, A, Bm, C, D2, h0)
     return y, hT

@@ -80,6 +80,11 @@ def _pow2floor(n: int) -> int:
     return 1 << (int(n).bit_length() - 1)
 
 
+def _uids(packed) -> list[int]:
+    """The uids of packed ``(slot, request)`` pairs, for a span's args."""
+    return [req.uid for _, req in packed]
+
+
 @dataclasses.dataclass
 class Request:
     uid: int
@@ -92,6 +97,9 @@ class Request:
     # by contract — a wall-clock step (NTP slew, manual set) must never
     # spuriously retire a request as timed_out or corrupt its latency
     t_submit: float = 0.0
+    # lifecycle start (perf_counter): when the request was due — its
+    # arrival time under serve_trace, else its submit time
+    t_due: float = 0.0
     speculative: bool = True           # opt this row out of spec drafting
                                        # (it then decodes plainly THROUGH
                                        # the verify pass — mixed waves)
@@ -146,9 +154,9 @@ class EngineStats:
     # per-request latency distributions, summarized from log-bucketed
     # histograms (core/telemetry.py::Histogram.summary: count/mean/p50/
     # p95/p99) — always recorded (a handful of perf_counter reads per
-    # dispatch), independent of whether global telemetry is enabled
+    # dispatch), independent of whether global telemetry is enabled; the
+    # queue wait is telemetry's ``engine.queue_s``
     ttft_hist: Optional[dict] = None       # time-to-first-token (s)
-    queue_hist: Optional[dict] = None      # queue wait (s)
     tok_latency_hist: Optional[dict] = None  # per-token decode latency (s)
     # SLA classes (submit(sla=...)): per-class latency distributions +
     # deadline misses — {cls: {ttft_hist, queue_hist, deadline_miss,
@@ -328,8 +336,9 @@ class DecodeEngine:
                              "merged-param requests is ambiguous)")
         uid = self._uid
         self._uid += 1
+        now = time.perf_counter()
         self._queue.append(Request(uid, tokens, int(max_new_tokens), extras,
-                                   domain, deadline_s, time.perf_counter(),
+                                   domain, deadline_s, now, now,
                                    bool(speculative),
                                    time.time(), sla))    # tracelint: ignore[R3] t_submit_wall is informational
         self._telemetry().count("engine.submitted")
@@ -417,11 +426,17 @@ class DecodeEngine:
         self._pool = pool
 
     def _admit_due(self) -> None:
-        """serve_trace: submit every arrival whose timestamp has passed."""
+        """serve_trace: submit every arrival whose timestamp has passed. The
+        request keeps its due time as its lifecycle start; how late this
+        sweep took it in is ``engine.admit_lag_s``."""
+        tel = self._telemetry()
         while self._arrivals and \
                 time.perf_counter() - self._trace_t0 >= self._arrivals[0][0]:
-            _, tokens, gen, kw = self._arrivals.popleft()
+            t, tokens, gen, kw = self._arrivals.popleft()
             self.submit(tokens, gen, **kw)
+            req = self._queue[-1]              # the request just submitted
+            req.t_due = self._trace_t0 + t
+            tel.observe("engine.admit_lag_s", req.t_submit - req.t_due)
 
     def _check_extras(self) -> frozenset:
         """Validate the all-or-none extras-keys invariant across the drain."""
@@ -452,7 +467,7 @@ class DecodeEngine:
         tel = self._telemetry()
         # drain-local latency histograms: always on (a few clock reads per
         # DISPATCH, never per token), summarized into EngineStats at exit
-        h_ttft, h_queue, h_tok = Histogram(), Histogram(), Histogram()
+        h_ttft, h_tok = Histogram(), Histogram()
         # per-SLA-class distributions (submit(sla=...)): lazily created
         # {cls: {"ttft": Histogram, "queue": Histogram, "miss": n, "n": n}}
         sla_acc: dict[str, dict] = {}
@@ -526,7 +541,7 @@ class DecodeEngine:
                 self._slot_blocks[i] = None
                 tel.gauge("engine.pool_blocks_used", self._alloc.used_blocks)
             tel.count("engine.retired")
-            tel.record_span("engine.request", req.t_submit, now,
+            tel.record_span("engine.request", req.t_due, now,
                             uid=req.uid, wave=slot_wave[i],
                             tokens=len(toks_i), domain=req.domain,
                             timed_out=timed_out)
@@ -538,8 +553,58 @@ class DecodeEngine:
         drain = tel.span("engine.drain", slots=B, queued=len(self._queue))
         drain.__enter__()
         while self._queue or remaining.any() or self._arrivals:
-            self._admit_due()
-            if not self._queue and not remaining.any():
+            # host work between one sync and the next dispatch is
+            # engine.schedule: admission, packing, the deadline sweep and
+            # the bookkeeping of served tokens
+            with tel.span("engine.schedule"):
+                self._admit_due()
+                idle = not self._queue and not remaining.any()
+                packed = [] if idle else self._fill_slots()
+                if packed:
+                    stats.waves += 1
+                    # a drain admitted entirely from a timed trace learns
+                    # its tenancy from the first packed wave (submit()
+                    # enforces the all-or-none invariant queue-wide)
+                    tenant = packed[0][1].domain is not None
+                    t_adm = time.perf_counter()  # queue wait ends: admitted
+                    for i, req in packed:
+                        slot_req[i], slot_wave[i] = req, stats.waves - 1
+                        remaining[i] = req.max_new_tokens
+                        cur_extras[i], cur_dom[i] = req.extras, req.domain
+                        spec_rows[i] = req.speculative
+                        t_admit[i], t_first[i] = t_adm, None
+                        tel.observe("engine.queue_s", t_adm - req.t_submit)
+                        if self.paged is not None:
+                            pb = self._slot_blocks[i]
+                            nshared = len(pb["shared"])
+                            stats.pool_blocks_alloc += len(pb["owned"])
+                            stats.cache_tokens += (len(req.tokens)
+                                                   + req.max_new_tokens
+                                                   - nshared * bs_)
+                            if nshared:
+                                stats.prefix_hits += 1
+                                stats.prefix_hit_tokens += nshared * bs_
+                                tel.count("engine.prefix_hits")
+                    if self.paged is not None:
+                        stats.pool_peak_blocks = max(stats.pool_peak_blocks,
+                                                     self._alloc.used_blocks)
+                        tel.gauge("engine.pool_blocks_used",
+                                  self._alloc.used_blocks)
+                        tel.gauge("engine.pool_blocks_shared",
+                                  sum(1 for rc in self._alloc.refcount
+                                      if rc > 1))
+                    live = [i for i in range(B) if slot_req[i] is not None]
+                    if tenant:                     # full-wave ids for segments
+                        doms = [cur_dom[i] if cur_dom[i] is not None
+                                else cur_dom[live[0]] for i in range(B)]
+                        ids = self.bank.adapter_ids(doms)
+                    wp = self._wave_params(params, tenant)
+                    # right-pad the PACKED prompts to a pow2 width (jit-
+                    # shape bucketing both dims keeps the compile cache
+                    # O(log² cap))
+                    S_pad = _pow2ceil(max(len(req.tokens)
+                                          for _, req in packed))
+            if idle:
                 # arrival-driven drain, nothing live yet: sleep toward the
                 # next arrival instead of spinning (capped so a deadline
                 # sweep never starves)
@@ -548,50 +613,7 @@ class DecodeEngine:
                 if dt > 0:
                     time.sleep(min(dt, 0.025))
                 continue
-            packed = self._fill_slots()
             if packed:
-                stats.waves += 1
-                # a drain admitted entirely from a timed trace learns its
-                # tenancy from the first packed wave (submit() enforces
-                # the all-or-none invariant queue-wide)
-                tenant = packed[0][1].domain is not None
-                t_adm = time.perf_counter()    # queue wait ends at admission
-                for i, req in packed:
-                    slot_req[i], slot_wave[i] = req, stats.waves - 1
-                    remaining[i] = req.max_new_tokens
-                    cur_extras[i], cur_dom[i] = req.extras, req.domain
-                    spec_rows[i] = req.speculative
-                    t_admit[i], t_first[i] = t_adm, None
-                    h_queue.record(t_adm - req.t_submit)
-                    tel.observe("engine.queue_s", t_adm - req.t_submit)
-                    if self.paged is not None:
-                        pb = self._slot_blocks[i]
-                        nshared = len(pb["shared"])
-                        stats.pool_blocks_alloc += len(pb["owned"])
-                        stats.cache_tokens += (len(req.tokens)
-                                               + req.max_new_tokens
-                                               - nshared * bs_)
-                        if nshared:
-                            stats.prefix_hits += 1
-                            stats.prefix_hit_tokens += nshared * bs_
-                            tel.count("engine.prefix_hits")
-                if self.paged is not None:
-                    stats.pool_peak_blocks = max(stats.pool_peak_blocks,
-                                                 self._alloc.used_blocks)
-                    tel.gauge("engine.pool_blocks_used",
-                              self._alloc.used_blocks)
-                    tel.gauge("engine.pool_blocks_shared",
-                              sum(1 for rc in self._alloc.refcount
-                                  if rc > 1))
-                live = [i for i in range(B) if slot_req[i] is not None]
-                if tenant:                     # full-wave ids for segments
-                    doms = [cur_dom[i] if cur_dom[i] is not None
-                            else cur_dom[live[0]] for i in range(B)]
-                    ids = self.bank.adapter_ids(doms)
-                wp = self._wave_params(params, tenant)
-                # right-pad the PACKED prompts to a pow2 width (jit-shape
-                # bucketing both dims keeps the compile cache O(log² cap))
-                S_pad = _pow2ceil(max(len(req.tokens) for _, req in packed))
                 if self.paged is not None:
                     # paged waves: dense-prefill the packed rows, then
                     # commit their K/V into the block pool through the
@@ -627,7 +649,7 @@ class DecodeEngine:
                         with tel.span("engine.prefill",
                                       wave=stats.waves - 1,
                                       rows=len(full_p), seq=S_pad,
-                                      paged=True):
+                                      paged=True, uids=_uids(full_p)):
                             tok, caches, pos = M._paged_prefill_fn(
                                 self.cfg, cap, bs_, self.mesh)(
                                 wp, batch, jnp.asarray(lens),
@@ -656,7 +678,7 @@ class DecodeEngine:
                         with tel.span("engine.refill",
                                       wave=stats.waves - 1,
                                       rows=len(full_p), seq=S_pad,
-                                      paged=True):
+                                      paged=True, uids=_uids(full_p)):
                             tok, caches, pos = M._paged_refill_fn(
                                 self.cfg, cap, bs_, self.mesh)(
                                 wp, batch, jnp.asarray(lens),
@@ -688,7 +710,8 @@ class DecodeEngine:
                             ids_rows = self.bank.adapter_ids(rdom)
                         with tel.span("engine.suffix",
                                       wave=stats.waves - 1,
-                                      rows=len(hit_p), seq=W):
+                                      rows=len(hit_p), seq=W,
+                                      uids=_uids(hit_p)):
                             tok, caches, pos = M._paged_suffix_fn(
                                 self.cfg, cap, bs_, self.mesh)(
                                 wp, jnp.asarray(suf), jnp.asarray(slens),
@@ -708,7 +731,8 @@ class DecodeEngine:
                                  [cur_extras[i] for i in range(B)],
                                  extras_keys, live)}
                     with tel.span("engine.prefill", wave=stats.waves - 1,
-                                  rows=len(packed), seq=S_pad):
+                                  rows=len(packed), seq=S_pad,
+                                  uids=_uids(packed)):
                         tok, caches, pos = M._wave_prefill_fn(
                             self.cfg, cap, self.mesh)(
                             wp, batch, jnp.asarray(lens), ids)
@@ -744,7 +768,8 @@ class DecodeEngine:
                         rdom += [rdom[0]] * (Br - len(packed))
                         ids_rows = self.bank.adapter_ids(rdom)
                     with tel.span("engine.refill", wave=stats.waves - 1,
-                                  rows=len(packed), seq=S_pad):
+                                  rows=len(packed), seq=S_pad,
+                                  uids=_uids(packed)):
                         tok, caches, pos = M._refill_fn(
                             self.cfg, cap, self.mesh)(
                             wp, batch, jnp.asarray(lens),
@@ -758,13 +783,14 @@ class DecodeEngine:
             # deadline sweep: a live row past its monotonic budget is
             # retired HERE, mid-wave, as a timed-out completion with the
             # tokens it has so far — over-budget rows never stall the drain
-            now = time.perf_counter()
-            for i in range(B):
-                req = slot_req[i]
-                if req is None or req.deadline_s is None:
-                    continue
-                if now - req.t_submit >= req.deadline_s:
-                    retire(i, now, timed_out=True)
+            with tel.span("engine.schedule"):
+                now = time.perf_counter()
+                for i in range(B):
+                    req = slot_req[i]
+                    if req is None or req.deadline_s is None:
+                        continue
+                    if now - req.t_submit >= req.deadline_s:
+                        retire(i, now, timed_out=True)
             if not remaining.any():
                 continue                       # re-pack freed slots (or exit)
             # segment length: with queued work, the pow2 floor of the
@@ -775,7 +801,7 @@ class DecodeEngine:
             # inside the scan idles finished rows either way; fewer
             # dispatches, identical padded_tokens).
             live_rem = remaining[remaining > 0]
-            live_n = int((remaining > 0).sum())
+            live_uids = [r.uid for r in slot_req if r is not None]
             t_seg0 = time.perf_counter()
             if self.spec is not None:
                 # speculative segment: `chunks` draft->verify chunks, each
@@ -788,18 +814,21 @@ class DecodeEngine:
                              else live_rem.max())
                 chunks = max(1, _pow2floor(max(1, budget // Tc)))
                 with tel.span("engine.segment", chunks=chunks, k=self.spec.k,
-                              live=live_n, speculative=True) as ssp:
-                    (toks, counts, dr, ac, tok, caches, dcaches, pos,
-                     _) = M._spec_segment_fn(
-                        self.cfg, self.spec.cfg, chunks, self.spec.k,
-                        self.mesh)(
-                        self._wave_params(params, tenant), self.spec.params,
-                        tok, caches, dcaches, pos,
-                        jnp.asarray(remaining, jnp.int32),
-                        jnp.asarray(spec_rows), ids)
-                    toks = np.asarray(toks)      # tracelint: ignore[R2] the ONE deliberate sync: segment done
-                    counts = np.asarray(counts)  # tracelint: ignore[R2] same fetch, already synced
-                    ssp.set(drafted=int(dr), accepted=int(ac))
+                              live=len(live_uids), speculative=True,
+                              uids=live_uids) as ssp:
+                    with tel.span("engine.dispatch"):
+                        (toks, counts, dr, ac, tok, caches, dcaches, pos,
+                         _) = M._spec_segment_fn(
+                            self.cfg, self.spec.cfg, chunks, self.spec.k,
+                            self.mesh)(
+                            self._wave_params(params, tenant),
+                            self.spec.params, tok, caches, dcaches, pos,
+                            jnp.asarray(remaining, jnp.int32),
+                            jnp.asarray(spec_rows), ids)
+                    with tel.span("engine.sync"):
+                        toks = np.asarray(toks)      # tracelint: ignore[R2] the ONE deliberate sync: segment done
+                        counts = np.asarray(counts)  # tracelint: ignore[R2] same fetch, already synced
+                        ssp.set(drafted=int(dr), accepted=int(ac))
                 stats.drafted += int(dr)
                 stats.accepted += int(ac)
                 executed = chunks * Tc * B     # verify slot-steps run
@@ -809,41 +838,45 @@ class DecodeEngine:
                 key = None
                 if not self.greedy:
                     self._key, key = jax.random.split(self._key)
-                with tel.span("engine.segment", seg=seg, live=live_n,
-                              speculative=False):
-                    toks, tok, caches, pos, _, key = M._segment_fn(
-                        self.cfg, seg, self.greedy, self.mesh)(
-                        self._wave_params(params, tenant), tok, caches, pos,
-                        jnp.asarray(remaining, jnp.int32), key, ids)
-                    toks = np.asarray(toks)    # tracelint: ignore[R2] the ONE deliberate sync: segment done
+                with tel.span("engine.segment", seg=seg,
+                              live=len(live_uids), speculative=False,
+                              uids=live_uids):
+                    with tel.span("engine.dispatch"):
+                        toks, tok, caches, pos, _, key = M._segment_fn(
+                            self.cfg, seg, self.greedy, self.mesh)(
+                            self._wave_params(params, tenant), tok, caches,
+                            pos, jnp.asarray(remaining, jnp.int32), key, ids)
+                    with tel.span("engine.sync"):
+                        toks = np.asarray(toks)    # tracelint: ignore[R2] the ONE deliberate sync: segment done
                 if key is not None:
                     self._key = key            # carried per-step splits
                 counts = np.minimum(seg, remaining)
                 executed = seg * B
             t_seg1 = time.perf_counter()
             seg_wall = t_seg1 - t_seg0
-            stats.segments += 1
-            served_now = 0
-            for i in range(B):
-                if remaining[i] <= 0:
-                    continue
-                served = int(counts[i])
-                bufs[i].append(toks[i, :served])
-                remaining[i] -= served
-                served_now += served
-                if served > 0:
-                    # per-token latency: this row's share of the segment
-                    # wall, one observation per served token
-                    h_tok.record(seg_wall / served, n=served)
-                    tel.observe("engine.tok_latency_s", seg_wall / served,
-                                n=served)
-                    if t_first[i] is None:     # first token host-visible
-                        t_first[i] = t_seg1
-                if remaining[i] == 0:          # retire: complete + free slot
-                    retire(i, t_seg1)
-            stats.tokens += served_now
-            stats.padded_tokens += executed - served_now
-            tel.observe("engine.segment_s", seg_wall)
+            with tel.span("engine.schedule"):
+                stats.segments += 1
+                served_now = 0
+                for i in range(B):
+                    if remaining[i] <= 0:
+                        continue
+                    served = int(counts[i])
+                    bufs[i].append(toks[i, :served])
+                    remaining[i] -= served
+                    served_now += served
+                    if served > 0:
+                        # per-token latency: this row's share of the
+                        # segment wall, one observation per served token
+                        h_tok.record(seg_wall / served, n=served)
+                        tel.observe("engine.tok_latency_s",
+                                    seg_wall / served, n=served)
+                        if t_first[i] is None:  # first token host-visible
+                            t_first[i] = t_seg1
+                    if remaining[i] == 0:       # retire: complete + free slot
+                        retire(i, t_seg1)
+                stats.tokens += served_now
+                stats.padded_tokens += executed - served_now
+                tel.observe("engine.segment_s", seg_wall)
         if self.paged is not None and caches is not None:
             # persist the committed pool across drains: a freed block's
             # K/V stays addressable until its slot is actually reused,
@@ -854,7 +887,6 @@ class DecodeEngine:
                 self._pool[g][s] = {"k": c["k"], "v": c["v"]}
         stats.wall_s = time.perf_counter() - t_all
         stats.ttft_hist = h_ttft.summary()
-        stats.queue_hist = h_queue.summary()
         stats.tok_latency_hist = h_tok.summary()
         if sla_acc:
             stats.sla_stats = {

@@ -63,12 +63,13 @@ def _proj(x, w, bias, lora, scale, adapter_ids=None):
     """
     if lora is not None:
         shp = x.shape
-        if adapter_ids is not None:
-            return kops.lora_bgmv(x, w, lora["a"], lora["b"], adapter_ids,
-                                  scale, bias)
-        y = kops.lora_matmul(x.reshape(-1, shp[-1]), w, lora["a"], lora["b"],
-                             scale, bias)
-        return y.reshape(*shp[:-1], w.shape[-1])
+        with jax.named_scope("lora"):
+            if adapter_ids is not None:
+                return kops.lora_bgmv(x, w, lora["a"], lora["b"],
+                                      adapter_ids, scale, bias)
+            y = kops.lora_matmul(x.reshape(-1, shp[-1]), w, lora["a"],
+                                 lora["b"], scale, bias)
+            return y.reshape(*shp[:-1], w.shape[-1])
     return kops.lora_matmul(x, w, bias=bias)
 
 
@@ -90,6 +91,7 @@ def _qkv(params, adapters, x, cfg: ModelConfig, kv_x=None, adapter_ids=None):
             v.reshape(B, Skv, nkv, hd))
 
 
+@jax.named_scope("kv_cache")
 def _with_prefix(k, v, adapters, B, adapter_ids=None):
     """Prepend per-layer prefix-KV slots (broadcast over batch; with
     ``adapter_ids`` each row gathers its own domain's slots from the
@@ -113,6 +115,7 @@ def _with_prefix(k, v, adapters, B, adapter_ids=None):
 # Full-sequence (train / prefill)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attn")
 def attention_seq(params: dict, adapters: Optional[dict], x: jax.Array,
                   cfg: ModelConfig, *, positions: jax.Array,
                   causal: bool = True, window: int = 0,
@@ -158,49 +161,58 @@ def attention_seq(params: dict, adapters: Optional[dict], x: jax.Array,
               cfg.peft.lora_alpha / max(cfg.peft.lora_rank, 1), adapter_ids)
     y = shard(y, "batch", "seq", "d_model")
 
-    cache = None
-    if make_cache:
-        lens = jnp.full((B,), S, jnp.int32) if lengths is None \
-            else lengths.astype(jnp.int32)
-        if window and window > 0:                          # rolling buffer, W slots
-            W = window
-            # slot s holds the largest position p ≡ s (mod W) with
-            # p <= len_b - 1 (the per-row rolling-buffer layout decode's
-            # ``pos % W`` writes continue); p < 0 means the slot is empty.
-            s_idx = jnp.arange(W, dtype=jnp.int32)
-            p = s_idx[None, :] + W * ((lens[:, None] - 1 - s_idx[None, :])
-                                      // W)                # (B, W)
-            valid = p >= 0
-            gidx = jnp.clip(p, 0, S - 1)[:, :, None, None]
-            cache_k = jnp.where(valid[:, :, None, None],
-                                jnp.take_along_axis(k, gidx, axis=1),
-                                jnp.zeros((), k.dtype))
-            cache_v = jnp.where(valid[:, :, None, None],
-                                jnp.take_along_axis(v, gidx, axis=1),
-                                jnp.zeros((), v.dtype))
-            # +1e9 sentinel: empty slots must be *invisible* (negative would
-            # mark them as always-visible prefix slots in the mask rules)
-            cpos = jnp.where(valid, p, 10 ** 9)
-            cache = {"k": cache_k, "v": cache_v, "pos": cpos}
-        else:
-            L = max(cache_len or S, S)
-            pad = L - S
-            base = jnp.pad(positions.astype(jnp.int32), (0, pad),
-                           constant_values=10 ** 9)        # (L,)
-            cpos = jnp.where(jnp.arange(L)[None, :] < lens[:, None],
-                             base[None, :], 10 ** 9)       # (B, L)
-            cache = {
-                "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                "pos": cpos,
-            }
+    cache = _prefill_cache(k, v, positions, S, cache_len, window,
+                           lengths) if make_cache else None
     return y, cache
+
+
+@jax.named_scope("kv_cache")
+def _prefill_cache(k, v, positions, S: int, cache_len, window: int, lengths):
+    """The layer's decode cache from its prompt K/V: a rolling buffer of
+    ``window`` slots for the sliding variant, else ``cache_len`` slots."""
+    B = k.shape[0]
+    lens = jnp.full((B,), S, jnp.int32) if lengths is None \
+        else lengths.astype(jnp.int32)
+    if window and window > 0:                      # rolling buffer, W slots
+        W = window
+        # slot s holds the largest position p ≡ s (mod W) with
+        # p <= len_b - 1 (the per-row rolling-buffer layout decode's
+        # ``pos % W`` writes continue); p < 0 means the slot is empty.
+        s_idx = jnp.arange(W, dtype=jnp.int32)
+        p = s_idx[None, :] + W * ((lens[:, None] - 1 - s_idx[None, :])
+                                  // W)                # (B, W)
+        valid = p >= 0
+        gidx = jnp.clip(p, 0, S - 1)[:, :, None, None]
+        cache_k = jnp.where(valid[:, :, None, None],
+                            jnp.take_along_axis(k, gidx, axis=1),
+                            jnp.zeros((), k.dtype))
+        cache_v = jnp.where(valid[:, :, None, None],
+                            jnp.take_along_axis(v, gidx, axis=1),
+                            jnp.zeros((), v.dtype))
+        # +1e9 sentinel: empty slots must be *invisible* (negative would
+        # mark them as always-visible prefix slots in the mask rules)
+        cpos = jnp.where(valid, p, 10 ** 9)
+        cache = {"k": cache_k, "v": cache_v, "pos": cpos}
+    else:
+        L = max(cache_len or S, S)
+        pad = L - S
+        base = jnp.pad(positions.astype(jnp.int32), (0, pad),
+                       constant_values=10 ** 9)        # (L,)
+        cpos = jnp.where(jnp.arange(L)[None, :] < lens[:, None],
+                         base[None, :], 10 ** 9)       # (B, L)
+        cache = {
+            "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
+            "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
+            "pos": cpos,
+        }
+    return cache
 
 
 # ---------------------------------------------------------------------------
 # Decode (single token against cache)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attn")
 def attention_decode(params: dict, adapters: Optional[dict], x: jax.Array,
                      cache: dict, cfg: ModelConfig, *, pos: jax.Array,
                      window: int = 0, cross: bool = False,
@@ -251,32 +263,33 @@ def attention_decode(params: dict, adapters: Optional[dict], x: jax.Array,
         if use_rope:
             k1 = rope(k1, pos[:, None], cfg.rope_theta)
         v1 = v1.reshape(B, 1, nkv, hd)
-        if "table" in cache:             # paged: block-table indirected write
-            table = cache["table"]
-            nb, bs = cache["k"].shape[0], cache["k"].shape[1]
-            blk = jnp.take_along_axis(table, (pos // bs)[:, None],
-                                      axis=1)[:, 0]
-            if active is not None:       # retired rows: write out of bounds
-                blk = jnp.where(active, blk, nb)
-            off = pos % bs
-            k = cache["k"].at[blk, off].set(
-                k1[:, 0].astype(cache["k"].dtype), mode="drop")
-            v = cache["v"].at[blk, off].set(
-                v1[:, 0].astype(cache["v"].dtype), mode="drop")
-            new_cache = {"k": k, "v": v, "table": table}
-            kv_pos = None                # implicit: slot index == position
-        else:
-            T = cache["k"].shape[1]
-            slot = (pos % window) if window and window > 0 else pos
-            if active is not None:       # retired rows: write out of bounds
-                slot = jnp.where(active, slot, T)
-            rows = jnp.arange(B)
-            k = cache["k"].at[rows, slot].set(
-                k1[:, 0].astype(cache["k"].dtype), mode="drop")
-            v = cache["v"].at[rows, slot].set(
-                v1[:, 0].astype(cache["v"].dtype), mode="drop")
-            kv_pos = cache["pos"].at[rows, slot].set(pos, mode="drop")
-            new_cache = {"k": k, "v": v, "pos": kv_pos}
+        with jax.named_scope("kv_cache"):
+            if "table" in cache:         # paged: block-table indirected write
+                table = cache["table"]
+                nb, bs = cache["k"].shape[0], cache["k"].shape[1]
+                blk = jnp.take_along_axis(table, (pos // bs)[:, None],
+                                          axis=1)[:, 0]
+                if active is not None:   # retired rows: write out of bounds
+                    blk = jnp.where(active, blk, nb)
+                off = pos % bs
+                k = cache["k"].at[blk, off].set(
+                    k1[:, 0].astype(cache["k"].dtype), mode="drop")
+                v = cache["v"].at[blk, off].set(
+                    v1[:, 0].astype(cache["v"].dtype), mode="drop")
+                new_cache = {"k": k, "v": v, "table": table}
+                kv_pos = None            # implicit: slot index == position
+            else:
+                T = cache["k"].shape[1]
+                slot = (pos % window) if window and window > 0 else pos
+                if active is not None:   # retired rows: write out of bounds
+                    slot = jnp.where(active, slot, T)
+                rows = jnp.arange(B)
+                k = cache["k"].at[rows, slot].set(
+                    k1[:, 0].astype(cache["k"].dtype), mode="drop")
+                v = cache["v"].at[rows, slot].set(
+                    v1[:, 0].astype(cache["v"].dtype), mode="drop")
+                kv_pos = cache["pos"].at[rows, slot].set(pos, mode="drop")
+                new_cache = {"k": k, "v": v, "pos": kv_pos}
 
     if "table" not in cache:
         k = shard(k, "batch", "kv_seq", "kv_heads", "head_dim")
@@ -294,8 +307,9 @@ def attention_decode(params: dict, adapters: Optional[dict], x: jax.Array,
     pfx_k = pfx_v = None
     if pfx is not None:
         if adapter_ids is not None:                # per-row domain prefix
-            pfx_k = jnp.take(pfx["k"], adapter_ids, axis=0)
-            pfx_v = jnp.take(pfx["v"], adapter_ids, axis=0)
+            with jax.named_scope("kv_cache"):
+                pfx_k = jnp.take(pfx["k"], adapter_ids, axis=0)
+                pfx_v = jnp.take(pfx["v"], adapter_ids, axis=0)
         else:
             pfx_k, pfx_v = pfx["k"], pfx["v"]
     if "table" in cache:
@@ -326,6 +340,7 @@ def chunk_slots(qpos: jax.Array, window: int, S: int,
     return slot
 
 
+@jax.named_scope("attn")
 def attention_verify(params: dict, adapters: Optional[dict], x: jax.Array,
                      cache: dict, cfg: ModelConfig, *, pos: jax.Array,
                      window: int = 0, use_rope: bool = True,
@@ -369,11 +384,12 @@ def attention_verify(params: dict, adapters: Optional[dict], x: jax.Array,
     S = cache["k"].shape[1]
     slot = chunk_slots(qpos, window, S, active)
     rows = jnp.arange(B)[:, None]
-    k = cache["k"].at[rows, slot].set(k1.astype(cache["k"].dtype),
-                                      mode="drop")
-    v = cache["v"].at[rows, slot].set(v1.astype(cache["v"].dtype),
-                                      mode="drop")
-    kv_pos = cache["pos"].at[rows, slot].set(qpos, mode="drop")
+    with jax.named_scope("kv_cache"):
+        k = cache["k"].at[rows, slot].set(k1.astype(cache["k"].dtype),
+                                          mode="drop")
+        v = cache["v"].at[rows, slot].set(v1.astype(cache["v"].dtype),
+                                          mode="drop")
+        kv_pos = cache["pos"].at[rows, slot].set(qpos, mode="drop")
     new_cache = {"k": k, "v": v, "pos": kv_pos}
 
     k = shard(k, "batch", "kv_seq", "kv_heads", "head_dim")
@@ -399,6 +415,7 @@ def attention_verify(params: dict, adapters: Optional[dict], x: jax.Array,
     return y, new_cache
 
 
+@jax.named_scope("attn")
 def attention_chunk_paged(params: dict, adapters: Optional[dict],
                           x: jax.Array, cache: dict, cfg: ModelConfig, *,
                           start: jax.Array, valid: jax.Array,
@@ -439,15 +456,15 @@ def attention_chunk_paged(params: dict, adapters: Optional[dict],
                                               table.shape[1] - 1), axis=1)
     blk = jnp.where(valid, blk, nb)               # pad tokens: dropped
     off = qpos % bs
-    pool_k = cache["k"].at[blk, off].set(k1.astype(cache["k"].dtype),
-                                         mode="drop")
-    pool_v = cache["v"].at[blk, off].set(v1.astype(cache["v"].dtype),
-                                         mode="drop")
+    with jax.named_scope("kv_cache"):
+        pool_k = cache["k"].at[blk, off].set(k1.astype(cache["k"].dtype),
+                                             mode="drop")
+        pool_v = cache["v"].at[blk, off].set(v1.astype(cache["v"].dtype),
+                                             mode="drop")
+        tbl = jnp.clip(table, 0, nb - 1)
+        kg = pool_k[tbl].reshape(B, -1, nkv, hd)  # (B, cap, Hkv, D)
+        vg = pool_v[tbl].reshape(B, -1, nkv, hd)
     new_cache = {"k": pool_k, "v": pool_v, "table": table}
-
-    tbl = jnp.clip(table, 0, nb - 1)
-    kg = pool_k[tbl].reshape(B, -1, nkv, hd)      # (B, cap, Hkv, D)
-    vg = pool_v[tbl].reshape(B, -1, nkv, hd)
     kv_pos = jnp.broadcast_to(
         jnp.arange(kg.shape[1], dtype=jnp.int32)[None], (B, kg.shape[1]))
     kp, vp, n_p = _with_prefix(kg, vg, adapters, B, adapter_ids)

@@ -72,6 +72,7 @@ def mlp_spec(d: int, ff: int, dtype=jnp.bfloat16) -> dict:
     }
 
 
+@jax.named_scope("mlp")
 def mlp(params: dict, x: jax.Array) -> jax.Array:
     h = jax.nn.silu(x @ params["gate"]) * (x @ params["up"])
     h = shard(h, *(("batch",) + ("attn_seq",) * (h.ndim - 2) + ("act_ff",))[-h.ndim:])

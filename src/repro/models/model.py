@@ -118,6 +118,30 @@ def input_pspec_axes(cfg: ModelConfig, shape: InputShape) -> dict:
 # ---------------------------------------------------------------------------
 
 
+# Named scopes split each program into the layers a profile reports:
+# ``embed``, ``layers`` (the layer scan; ``layer`` is its body), ``lm_head``
+# (final norm, unembedding and the loss) and ``sample``; inside a layer
+# ``attn`` (with ``kv_cache`` and ``lora``) and ``mlp``; ``steps`` around a
+# decode segment's step loop, whose own ops are the copies XLA places
+# around the layer scan. Scopes change only the ops' metadata, never the
+# program.
+
+
+@jax.named_scope("embed")
+def _embed_tokens(params: dict, tokens: jax.Array) -> jax.Array:
+    x = embed(params["backbone"]["embed"], tokens)
+    return shard(x, "batch", "seq", "d_model")
+
+
+@jax.named_scope("lm_head")
+def _lm_head(params: dict, x: jax.Array) -> jax.Array:
+    """Final norm and unembedding: logits."""
+    x = rmsnorm(params["backbone"]["final_norm"], x)
+    head_tbl = params["backbone"].get("lm_head", params["backbone"]["embed"])
+    return unembed(head_tbl, x)
+
+
+@jax.named_scope("embed")
 def _embed_inputs(params: dict, batch: dict, cfg: ModelConfig):
     """Token (+modality) embedding. Returns (x, positions, label_offset)."""
     x = embed(params["backbone"]["embed"], batch["tokens"])
@@ -158,10 +182,12 @@ def forward(params: dict, batch: dict, cfg: ModelConfig, *,
         x, _, aux = stack_seq(params["backbone"]["layers"], adapters, x, cfg,
                               positions=positions, remat=remat,
                               adapter_ids=adapter_ids)
-    x = rmsnorm(params["backbone"]["final_norm"], x)
-    head_tbl = params["backbone"].get("lm_head", params["backbone"]["embed"])
-    logits = unembed(head_tbl, x)
-    logits = shard(logits, "batch", "seq", "vocab")
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(params["backbone"]["final_norm"], x)
+        head_tbl = params["backbone"].get("lm_head",
+                                          params["backbone"]["embed"])
+        logits = unembed(head_tbl, x)
+        logits = shard(logits, "batch", "seq", "vocab")
     return {"hidden": x, "logits": logits, "aux": aux}
 
 
@@ -170,9 +196,10 @@ def lm_loss(params: dict, batch: dict, cfg: ModelConfig, *,
     out = forward(params, batch, cfg, mode="train", remat=remat)
     logits = out["logits"]
     labels = batch["labels"]
-    if logits.shape[1] != labels.shape[1]:          # vlm: loss on text only
-        logits = logits[:, -labels.shape[1]:]
-    loss = cross_entropy(logits, labels) + out["aux"]
+    with jax.named_scope("lm_head"):
+        if logits.shape[1] != labels.shape[1]:      # vlm: loss on text only
+            logits = logits[:, -labels.shape[1]:]
+        loss = cross_entropy(logits, labels) + out["aux"]
     return loss, {"aux": out["aux"]}
 
 
@@ -241,14 +268,13 @@ def prefill(params: dict, batch: dict, cfg: ModelConfig,
                                  cfg, positions=positions, make_cache=True,
                                  remat=False, cache_len=max_len,
                                  adapter_ids=adapter_ids, lengths=lengths)
-    if lengths is None:
-        x = x[:, -1:]
-    else:                                  # per-row last VALID token
-        B = x.shape[0]
-        x = x[jnp.arange(B)[:, None], (lengths - 1)[:, None]]
-    x = rmsnorm(params["backbone"]["final_norm"], x)
-    head_tbl = params["backbone"].get("lm_head", params["backbone"]["embed"])
-    return unembed(head_tbl, x), caches
+    with jax.named_scope("lm_head"):
+        if lengths is None:
+            x = x[:, -1:]
+        else:                              # per-row last VALID token
+            B = x.shape[0]
+            x = x[jnp.arange(B)[:, None], (lengths - 1)[:, None]]
+    return _lm_head(params, x), caches
 
 
 def _scan_steps(params: dict, cfg: ModelConfig, steps: int, greedy: bool,
@@ -275,19 +301,21 @@ def _scan_steps(params: dict, cfg: ModelConfig, steps: int, greedy: bool,
         active = remaining > 0
         logits, caches = decode_step(params, tok, caches, pos, cfg,
                                      adapter_ids=adapter_ids, active=active)
-        if greedy:
-            nxt = jnp.argmax(logits[:, -1], axis=-1)[:, None]
-        else:
-            key, sub = jax.random.split(key)
-            nxt = jax.random.categorical(sub, logits[:, -1])[:, None]
-        nxt = jnp.where(active[:, None], nxt.astype(jnp.int32), tok)
+        with jax.named_scope("sample"):
+            if greedy:
+                nxt = jnp.argmax(logits[:, -1], axis=-1)[:, None]
+            else:
+                key, sub = jax.random.split(key)
+                nxt = jax.random.categorical(sub, logits[:, -1])[:, None]
+            nxt = jnp.where(active[:, None], nxt.astype(jnp.int32), tok)
         pos = pos + active.astype(jnp.int32)
         remaining = remaining - active.astype(jnp.int32)
         ys = (tok, rec_cache_part(caches)) if with_state else tok
         return (nxt, caches, pos, remaining, key), ys
 
-    carry, ys = jax.lax.scan(step, (tok, caches, pos, remaining, key),
-                             None, length=steps)
+    with jax.named_scope("steps"):
+        carry, ys = jax.lax.scan(step, (tok, caches, pos, remaining, key),
+                                 None, length=steps)
     if with_state:
         toks, snaps = ys
         snaps = jax.tree.map(lambda s: jnp.moveaxis(s, 0, 2), snaps)
@@ -305,7 +333,8 @@ def _prefill_state(params: dict, batch: dict, cfg: ModelConfig, cap: int,
     n_vis = cfg.vlm.n_vis_tokens if cfg.family == "vlm" else 0
     logits, caches = prefill(params, batch, cfg, max_len=cap + n_vis,
                              adapter_ids=adapter_ids, prompt_lens=prompt_lens)
-    tok0 = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+    with jax.named_scope("sample"):
+        tok0 = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
     B = batch["tokens"].shape[0]
     if prompt_lens is None:
         pos0 = jnp.full((B,), S + n_vis, jnp.int32)
@@ -331,12 +360,12 @@ def _wave_rules(mesh):
 def _wave_prefill_fn(cfg: ModelConfig, cap: int, mesh=None):
     """Jitted ragged wave prefill: batch + prompt_lens -> decode state."""
 
-    def impl(params, batch, prompt_lens, adapter_ids):
+    def wave_prefill(params, batch, prompt_lens, adapter_ids):
         with _wave_rules(mesh):
             return _prefill_state(params, batch, cfg, cap, adapter_ids,
                                   prompt_lens)
 
-    return jax.jit(impl)
+    return jax.jit(wave_prefill)
 
 
 # tracelint: keys=cfg,cap,mesh
@@ -354,8 +383,8 @@ def _refill_fn(cfg: ModelConfig, cap: int, mesh=None):
     freed slot is re-prefilled between scan segments at the cost of a
     refill-sized prefill, not a wave-sized one."""
 
-    def impl(params, batch, prompt_lens, row_idx, tok, caches, pos,
-             adapter_ids):
+    def refill(params, batch, prompt_lens, row_idx, tok, caches, pos,
+               adapter_ids):
         with _wave_rules(mesh):
             tok_n, caches_n, pos_n = _prefill_state(params, batch, cfg, cap,
                                                     adapter_ids, prompt_lens)
@@ -364,12 +393,13 @@ def _refill_fn(cfg: ModelConfig, cap: int, mesh=None):
                 return old.at[:, row_idx].set(new.astype(old.dtype),
                                               mode="drop")
 
-            caches = jax.tree.map(merge, caches, caches_n)
+            with jax.named_scope("kv_cache"):
+                caches = jax.tree.map(merge, caches, caches_n)
             tok = tok.at[row_idx].set(tok_n, mode="drop")
             pos = pos.at[row_idx].set(pos_n, mode="drop")
             return tok, caches, pos
 
-    return jax.jit(impl)
+    return jax.jit(refill)
 
 
 # tracelint: keys=cfg,steps,greedy,mesh
@@ -381,18 +411,20 @@ def _segment_fn(cfg: ModelConfig, steps: int, greedy: bool, mesh=None):
     jit cache stays O(log max_budget) across any mix of per-row budgets
     instead of growing per distinct budget."""
 
-    def impl(params, tok, caches, pos, remaining, key, adapter_ids):
+    def decode_segment(params, tok, caches, pos, remaining, key,
+                       adapter_ids):
         with _wave_rules(mesh):
             toks, (tok, caches, pos, remaining, key) = _scan_steps(
                 params, cfg, steps, greedy, tok, caches, pos, remaining, key,
                 adapter_ids)
             return toks, tok, caches, pos, remaining, key
 
-    return jax.jit(impl)
+    return jax.jit(decode_segment)
 
 
 # -- paged KV cache (block pool + per-row tables) ---------------------------
 
+@jax.named_scope("kv_cache")
 def _pool_commit(pool_sub: dict, dense_k, dense_v, tables, lens):
     """Scatter dense prefill K/V for B rows into the block pool.
 
@@ -432,7 +464,8 @@ def _paged_prefill_fn(cfg: ModelConfig, cap: int, bs: int, mesh=None):
     tree {group: {sub: {'k','v'}}} for eligible subs."""
     subs = frozenset(paged_subs(cfg))
 
-    def impl(params, batch, prompt_lens, tables, pool, adapter_ids):
+    def paged_prefill(params, batch, prompt_lens, tables, pool,
+                      adapter_ids):
         with _wave_rules(mesh):
             tok0, dense, pos0 = _prefill_state(params, batch, cfg, cap,
                                                adapter_ids, prompt_lens)
@@ -455,7 +488,7 @@ def _paged_prefill_fn(cfg: ModelConfig, cap: int, bs: int, mesh=None):
                         caches[g][s] = c
             return tok0, caches, pos0
 
-    return jax.jit(impl)
+    return jax.jit(paged_prefill)
 
 
 # tracelint: keys=cfg,cap,bs,mesh
@@ -466,8 +499,8 @@ def _paged_refill_fn(cfg: ModelConfig, cap: int, bs: int, mesh=None):
     ``row_idx``; ineligible leaves row-merge exactly like _refill_fn."""
     subs = frozenset(paged_subs(cfg))
 
-    def impl(params, batch, prompt_lens, row_idx, tables_rows, tok, caches,
-             pos, adapter_ids):
+    def paged_refill(params, batch, prompt_lens, row_idx, tables_rows, tok,
+                     caches, pos, adapter_ids):
         with _wave_rules(mesh):
             tok_n, dense_n, pos_n = _prefill_state(params, batch, cfg, cap,
                                                    adapter_ids, prompt_lens)
@@ -496,7 +529,7 @@ def _paged_refill_fn(cfg: ModelConfig, cap: int, bs: int, mesh=None):
             pos = pos.at[row_idx].set(pos_n, mode="drop")
             return tok, out, pos
 
-    return jax.jit(impl)
+    return jax.jit(paged_refill)
 
 
 # tracelint: keys=cfg,cap,bs,mesh
@@ -512,8 +545,8 @@ def _paged_suffix_fn(cfg: ModelConfig, cap: int, bs: int, mesh=None):
     first decode token + position. Requires a fully paged stack (the
     engine gates prefix sharing to such configs)."""
 
-    def impl(params, tokens, suffix_lens, start, row_idx, tables_rows,
-             tok, caches, pos, adapter_ids):
+    def paged_suffix(params, tokens, suffix_lens, start, row_idx,
+                     tables_rows, tok, caches, pos, adapter_ids):
         with _wave_rules(mesh):
             adapters = params.get("adapters", {}).get("stack", {})
             Br, W = tokens.shape
@@ -521,8 +554,7 @@ def _paged_suffix_fn(cfg: ModelConfig, cap: int, bs: int, mesh=None):
             maxb = tables_rows.shape[1]
             suffix_lens = suffix_lens.astype(jnp.int32)
             start = start.astype(jnp.int32)
-            x = embed(params["backbone"]["embed"], tokens)
-            x = shard(x, "batch", "seq", "d_model")
+            x = _embed_tokens(params, tokens)
             valid = jnp.arange(W, dtype=jnp.int32)[None, :] \
                 < suffix_lens[:, None]
             sub_caches = {
@@ -534,14 +566,13 @@ def _paged_suffix_fn(cfg: ModelConfig, cap: int, bs: int, mesh=None):
             x, new_sub = stack_chunk(params["backbone"]["layers"], adapters,
                                      x, sub_caches, cfg, start=start,
                                      valid=valid, adapter_ids=adapter_ids)
-            xl = x[jnp.arange(Br)[:, None],
-                   jnp.maximum(suffix_lens - 1, 0)[:, None]]
-            xl = rmsnorm(params["backbone"]["final_norm"], xl)
-            head_tbl = params["backbone"].get("lm_head",
-                                              params["backbone"]["embed"])
-            logits = unembed(head_tbl, xl)
-            tok_n = jnp.argmax(logits[:, -1], axis=-1)[:, None] \
-                .astype(jnp.int32)
+            with jax.named_scope("lm_head"):
+                xl = x[jnp.arange(Br)[:, None],
+                       jnp.maximum(suffix_lens - 1, 0)[:, None]]
+            logits = _lm_head(params, xl)
+            with jax.named_scope("sample"):
+                tok_n = jnp.argmax(logits[:, -1], axis=-1)[:, None] \
+                    .astype(jnp.int32)
             pos_n = start + suffix_lens
             out = {}
             for g, grp in caches.items():
@@ -557,7 +588,7 @@ def _paged_suffix_fn(cfg: ModelConfig, cap: int, bs: int, mesh=None):
             pos = pos.at[row_idx].set(pos_n, mode="drop")
             return tok, out, pos
 
-    return jax.jit(impl)
+    return jax.jit(paged_suffix)
 
 
 # Fused-fn cache-key invariant: every trace-shaping argument must appear
@@ -588,12 +619,12 @@ def _draft_fn(dcfg: ModelConfig, k: int, mesh=None):
 
     from repro.core import spec_decode as sd                # lazy: no cycle
 
-    def impl(dparams, tok, dcaches, pos, active):
+    def draft(dparams, tok, dcaches, pos, active):
         with _wave_rules(mesh):
             return sd.draft_chunk(dparams, dcfg, k, tok, dcaches, pos,
                                   active)
 
-    return jax.jit(impl)
+    return jax.jit(draft)
 
 
 # tracelint: keys=cfg,mesh
@@ -601,12 +632,12 @@ def _draft_fn(dcfg: ModelConfig, k: int, mesh=None):
 def _verify_fn(cfg: ModelConfig, mesh=None):
     """Jitted one-pass chunk verify (see verify_step)."""
 
-    def impl(params, tokens, caches, pos, active, adapter_ids):
+    def verify(params, tokens, caches, pos, active, adapter_ids):
         with _wave_rules(mesh):
             return verify_step(params, tokens, caches, pos, cfg,
                                adapter_ids=adapter_ids, active=active)
 
-    return jax.jit(impl)
+    return jax.jit(verify)
 
 
 # tracelint: keys=cfg,dcfg,chunks,k,mesh
@@ -618,14 +649,14 @@ def _spec_segment_fn(cfg: ModelConfig, dcfg: ModelConfig, chunks: int,
     counts are pow2-bucketed by the engine, mirroring _segment_fn."""
     from repro.core import spec_decode as sd                # lazy: no cycle
 
-    def impl(params, dparams, tok, caches, dcaches, pos, remaining,
-             spec_rows, adapter_ids):
+    def spec_segment(params, dparams, tok, caches, dcaches, pos, remaining,
+                     spec_rows, adapter_ids):
         with _wave_rules(mesh):
             return sd.spec_segment(params, dparams, cfg, dcfg, chunks, k,
                                    tok, caches, dcaches, pos, remaining,
                                    spec_rows, adapter_ids, mesh=mesh)
 
-    return jax.jit(impl)
+    return jax.jit(spec_segment)
 
 
 # tracelint: keys=cfg,gen,greedy,mesh
@@ -641,8 +672,8 @@ def _generate_fn(cfg: ModelConfig, gen: int, greedy: bool, mesh=None):
     jit re-specializes per input shape as usual.
     """
 
-    def impl(params: dict, batch: dict, key: jax.Array,
-             adapter_ids, prompt_lens) -> jax.Array:
+    def generate(params: dict, batch: dict, key: jax.Array,
+                 adapter_ids, prompt_lens) -> jax.Array:
         with _wave_rules(mesh):
             S = batch["tokens"].shape[1]
             tok0, caches, pos0 = _prefill_state(params, batch, cfg, S + gen,
@@ -653,7 +684,7 @@ def _generate_fn(cfg: ModelConfig, gen: int, greedy: bool, mesh=None):
                                   pos0, remaining, key, adapter_ids)
             return toks                                    # (B, gen)
 
-    return jax.jit(impl)
+    return jax.jit(generate)
 
 
 def place_params(params: dict, cfg: ModelConfig, mesh,
@@ -720,8 +751,7 @@ def decode_step(params: dict, token: jax.Array, caches: dict,
     adapters = params.get("adapters", {}).get("stack", {})
     B = token.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-    x = embed(params["backbone"]["embed"], token)
-    x = shard(x, "batch", "seq", "d_model")
+    x = _embed_tokens(params, token)
     if cfg.family == "audio":
         x, caches = encdec.decode_step(params["backbone"]["encdec"], adapters,
                                        x, caches, cfg, pos=pos, active=active)
@@ -729,10 +759,7 @@ def decode_step(params: dict, token: jax.Array, caches: dict,
         x, caches = stack_decode(params["backbone"]["layers"], adapters, x,
                                  caches, cfg, pos=pos,
                                  adapter_ids=adapter_ids, active=active)
-    x = rmsnorm(params["backbone"]["final_norm"], x)
-    head_tbl = params["backbone"].get("lm_head", params["backbone"]["embed"])
-    logits = unembed(head_tbl, x)
-    return logits, caches
+    return _lm_head(params, x), caches
 
 
 def verify_step(params: dict, tokens: jax.Array, caches: dict,
@@ -754,11 +781,8 @@ def verify_step(params: dict, tokens: jax.Array, caches: dict,
     adapters = params.get("adapters", {}).get("stack", {})
     B = tokens.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-    x = embed(params["backbone"]["embed"], tokens)
-    x = shard(x, "batch", "seq", "d_model")
+    x = _embed_tokens(params, tokens)
     x, caches, snaps = stack_verify(params["backbone"]["layers"], adapters,
                                     x, caches, cfg, pos=pos,
                                     adapter_ids=adapter_ids, active=active)
-    x = rmsnorm(params["backbone"]["final_norm"], x)
-    head_tbl = params["backbone"].get("lm_head", params["backbone"]["embed"])
-    return unembed(head_tbl, x), caches, snaps
+    return _lm_head(params, x), caches, snaps

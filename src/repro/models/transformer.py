@@ -287,6 +287,7 @@ def stack_seq(params: dict, adapters: dict, x: jax.Array, cfg: ModelConfig, *,
     for name, kinds, n in groups_for(cfg):
         gp, ga = params[name], adapters.get(name, {})
 
+        @jax.named_scope("layer")
         def body(carry, layer):
             x, aux = carry
             lp, la = layer
@@ -305,8 +306,9 @@ def stack_seq(params: dict, adapters: dict, x: jax.Array, cfg: ModelConfig, *,
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        (x, aux_total), gcache = jax.lax.scan(
-            body, (x, aux_total), (gp, ga if ga else _empty_like(gp, n)))
+        with jax.named_scope("layers"):
+            (x, aux_total), gcache = jax.lax.scan(
+                body, (x, aux_total), (gp, ga if ga else _empty_like(gp, n)))
         caches[name] = gcache
     return x, (caches if make_cache else None), aux_total
 
@@ -323,6 +325,7 @@ def stack_decode(params: dict, adapters: dict, x: jax.Array,
         gp, ga = params[name], adapters.get(name, {})
         gc = caches[name]
 
+        @jax.named_scope("layer")
         def body(x, layer):
             lp, la, lc = layer
             new_lc = {}
@@ -335,8 +338,9 @@ def stack_decode(params: dict, adapters: dict, x: jax.Array,
                 new_lc[key] = c
             return x, new_lc
 
-        x, new_gc = jax.lax.scan(
-            body, x, (gp, ga if ga else _empty_like(gp, n), gc))
+        with jax.named_scope("layers"):
+            x, new_gc = jax.lax.scan(
+                body, x, (gp, ga if ga else _empty_like(gp, n), gc))
         new_caches[name] = new_gc
     return x, new_caches
 
@@ -367,6 +371,7 @@ def stack_chunk(params: dict, adapters: dict, x: jax.Array, caches: dict,
         gp, ga = params[name], adapters.get(name, {})
         gc = caches[name]
 
+        @jax.named_scope("layer")
         def body(x, layer):
             lp, la, lc = layer
             new_lc = {}
@@ -388,8 +393,9 @@ def stack_chunk(params: dict, adapters: dict, x: jax.Array, caches: dict,
                 new_lc[key] = c
             return x, new_lc
 
-        x, new_gc = jax.lax.scan(
-            body, x, (gp, ga if ga else _empty_like(gp, n), gc))
+        with jax.named_scope("layers"):
+            x, new_gc = jax.lax.scan(
+                body, x, (gp, ga if ga else _empty_like(gp, n), gc))
         new_caches[name] = new_gc
     return x, new_caches
 
@@ -415,6 +421,7 @@ def stack_verify(params: dict, adapters: dict, x: jax.Array, caches: dict,
         gp, ga = params[name], adapters.get(name, {})
         gc = caches[name]
 
+        @jax.named_scope("layer")
         def body(x, layer):
             lp, la, lc = layer
             new_lc, snap_lc = {}, {}
@@ -451,8 +458,9 @@ def stack_verify(params: dict, adapters: dict, x: jax.Array, caches: dict,
                 snap_lc[key] = s
             return x, (new_lc, snap_lc)
 
-        x, (new_gc, snap_gc) = jax.lax.scan(
-            body, x, (gp, ga if ga else _empty_like(gp, n), gc))
+        with jax.named_scope("layers"):
+            x, (new_gc, snap_gc) = jax.lax.scan(
+                body, x, (gp, ga if ga else _empty_like(gp, n), gc))
         new_caches[name] = new_gc
         snaps[name] = snap_gc
     return x, new_caches, snaps

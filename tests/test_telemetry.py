@@ -190,9 +190,10 @@ def test_engine_lifecycle_metrics(setup):
         assert c.ttft_s is not None and c.ttft_s >= c.queue_s
         assert c.latency_s >= c.ttft_s
         assert c.tok_s > 0
-    # histogram summaries are always on (independent of telemetry state)
+    # histogram summaries are always on (independent of telemetry state);
+    # the queue wait is telemetry's own histogram
     assert stats.ttft_hist["count"] == 4
-    assert stats.queue_hist["count"] == 4
+    assert tel.hist_summary("engine.queue_s")["count"] == 4
     assert stats.tok_latency_hist["count"] == sum(budgets)
     assert stats.ttft_hist["p50"] <= stats.ttft_hist["p99"]
     # opt-in global spans: one lifecycle span per request, segments, drain
@@ -225,3 +226,83 @@ def test_engine_deadlines_survive_wall_clock_jump(setup, monkeypatch):
         assert c.tokens.shape == (3,)
         assert 0 <= c.latency_s < 300.0          # not an hour
         assert c.ttft_s is not None and 0 <= c.ttft_s <= c.latency_s
+
+
+# -- spans on the profiler's clock ------------------------------------------
+def _host_events(logdir):
+    """(name, start ns, end ns, args) of every host event of a profile."""
+    (path,) = logdir.glob("plugins/profile/*/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_engine_spans_land_in_the_profile(setup, tmp_path):
+    """Inside jax.profiler.trace an enabled Telemetry's spans are host events
+    of the profile, with their arguments: every segment holds one dispatch
+    and one sync; schedule and refill spans lie between segments; segments
+    and refills name the requests they serve. A disabled Telemetry adds
+    no event."""
+    cfg, params = setup
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(5), (4, 8), 0, cfg.vocab_size, dtype=jnp.int32))
+
+    def drain(tel):
+        engine = DecodeEngine(cfg, slots=2, tel=tel)
+        uids = [engine.submit(p, g) for p, g in zip(prompts, [2, 5, 3, 4])]
+        engine.run(params)
+        return uids
+
+    drain(Telemetry(enabled=False))            # compile outside the traces
+    with jax.profiler.trace(str(tmp_path / "on")):
+        uids = drain(Telemetry(enabled=True))
+    with jax.profiler.trace(str(tmp_path / "off")):
+        drain(Telemetry(enabled=False))
+
+    on = _host_events(tmp_path / "on")
+    by = lambda n: [e for e in on if e[0] == n]
+    segs = by("engine.segment")
+    assert segs and by("engine.prefill") and by("engine.refill")
+    for child in ("engine.dispatch", "engine.sync"):
+        evs = by(child)
+        assert len(evs) == len(segs)
+        assert all(any(s0 <= t0 and t1 <= s1 for _, s0, s1, _ in segs)
+                   for _, t0, t1, _ in evs)
+    assert by("engine.schedule")
+    for name in ("engine.schedule", "engine.refill"):
+        for _, t0, t1, _ in by(name):
+            assert not any(t0 < s1 and s0 < t1 for _, s0, s1, _ in segs)
+    served = set()
+    for *_, args in segs + by("engine.prefill") + by("engine.refill"):
+        served |= set(json.loads(args["uids"]))
+    assert served == set(uids)
+    assert all("wave" in a for *_, a in by("engine.refill"))
+    (drain_ev,) = by("engine.drain")
+    assert all(drain_ev[1] <= t0 and t1 <= drain_ev[2]
+               for _, t0, t1, _ in segs)
+    off = _host_events(tmp_path / "off")
+    assert not any(n.startswith("engine.") for n, *_ in off)
+
+
+def test_request_lifecycle_starts_when_due(setup):
+    """serve_trace keeps each arrival's due time: its engine.request span
+    starts there, engine.admit_lag_s records how late the admission sweep
+    took it in, and the Completion's timings stay anchored at submit."""
+    cfg, params = setup
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(6), (4, 8), 0, cfg.vocab_size, dtype=jnp.int32))
+    tel = Telemetry(enabled=True)
+    engine = DecodeEngine(cfg, slots=2, tel=tel)
+    arrivals = [(0.0, prompts[0], 3), (0.0, prompts[1], 6),
+                (0.02, prompts[2], 2), (0.05, prompts[3], 2)]
+    comps, _ = engine.serve_trace(params, arrivals)
+    lag = tel.hists["engine.admit_lag_s"]
+    assert lag.n == 4 and lag.vmin >= 0
+    by_uid = {c.uid: c for c in comps}
+    spans = [sp for sp in tel.spans if sp.name == "engine.request"]
+    assert sorted(sp.args["uid"] for sp in spans) == sorted(by_uid)
+    lags = [sp.dur - by_uid[sp.args["uid"]].latency_s for sp in spans]
+    assert min(lags) >= -1e-9
+    assert sum(lags) == pytest.approx(lag.total, abs=1e-6)
