@@ -42,7 +42,8 @@ got = ops.flash_attention(q, k, v, q_pos=qp, kv_pos=kp, block_q=8,
 np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3)
 
 want = ref.decode_attention(q[:, -1], k, v, q_pos=S - 1, kv_pos=kp)
-got = ops.flash_decode(q[:, -1], k, v, q_pos=S - 1, kv_pos=kp,
+hm = lambda t: jnp.swapaxes(t, 1, 2)          # resident head-major cache
+got = ops.flash_decode(q[:, -1], hm(k), hm(v), q_pos=S - 1, kv_pos=kp,
                        backend="interpret")
 np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3)
 
@@ -56,7 +57,7 @@ kp_rag = jnp.where(jnp.arange(T)[None, :] < written[:, None],
                    jnp.arange(T)[None, :], 10 ** 9)     # (2, T)
 qp_rag = written - 1                                    # (2,)
 want = ref.decode_attention(q2, k2, v2, q_pos=qp_rag, kv_pos=kp_rag)
-got = ops.flash_decode(q2, k2, v2, q_pos=qp_rag, kv_pos=kp_rag,
+got = ops.flash_decode(q2, hm(k2), hm(v2), q_pos=qp_rag, kv_pos=kp_rag,
                        backend="interpret")
 np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3)
 
@@ -251,8 +252,8 @@ for backend in ("xla", "interpret"):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3)
 # fp32 bit-parity paged-vs-dense on the engine's xla path: same visible
 # values + same accumulation order == bitwise-equal decode outputs
-k = k_pool[table].reshape(B, maxb * bs, Hkv, D)
-v = v_pool[table].reshape(B, maxb * bs, Hkv, D)
+k = jnp.swapaxes(k_pool[table].reshape(B, maxb * bs, Hkv, D), 1, 2)
+v = jnp.swapaxes(v_pool[table].reshape(B, maxb * bs, Hkv, D), 1, 2)
 dense = ops.flash_decode(q, k, v, q_pos=q_pos,
                          kv_pos=jnp.arange(maxb * bs, dtype=jnp.int32),
                          window=0, causal=True, backend="xla")

@@ -169,7 +169,10 @@ def _restore_attn(old: dict, new: dict, *, qpos, commit, active, window):
     gidx = jnp.clip(slot, 0, S - 1)
 
     def fix(o, n):
-        return n.at[:, rows, slot].set(o[:, rows, gidx], mode="drop")
+        if n.ndim == 3:                              # pos: (L, B, S)
+            return n.at[:, rows, slot].set(o[:, rows, gidx], mode="drop")
+        return n.at[:, rows, :, slot].set(o[:, rows, :, gidx],  # head-major
+                                          mode="drop")
 
     return {key: fix(old[key], new[key]) for key in old}
 

@@ -13,14 +13,17 @@ written on the last chunk. GQA is expressed in the index_maps: the
 sublane dim of a single ``(g, D)`` q tile, so grouped queries ride along for
 free instead of duplicating KV reads per query head.
 
-Layout (what the TPU compiler accepts): the cache is viewed as
-``(B, T, Hkv * Dp)`` and the pool as ``(n_blocks, bs, Hkv * Dp)``, so one
-KV head's chunk is a ``(block, Dp)`` lane-aligned column slab; per-row
-query positions (and the paged block table) are scalar-prefetched into
-SMEM; the dense kernel's kv positions ride as a ``(B, nk, block_kv)`` plane
-whose whole-row block stays resident in VMEM across the chunk sweep (each
-step reads its ``(1, block_kv)`` row), which keeps any ``block_kv`` that is
-a multiple of 8 legal.
+Layout (what the TPU compiler accepts): the dense kernel reads the
+resident layer-stacked cache ``(L, B, Hkv, T, D)`` as it lies — one KV
+head's chunk is a ``(block_kv, D)`` block of the stored array, and the
+layer index rides in SMEM with the per-row query positions (scalar
+prefetch), so no cache-sized copy precedes the kernel. The prefix bank is
+a small operand of its own, folded into the online-softmax state before the
+chunk sweep. The pool is viewed as ``(n_blocks, bs, Hkv * Dp)``, so one KV
+head's block is a ``(bs, Dp)`` lane-aligned column slab, with the block
+table scalar-prefetched. The dense kernel's kv positions ride as a
+``(B, nk, block_kv)`` plane whose whole-row block stays resident in VMEM
+across the chunk sweep (each step reads its ``(1, block_kv)`` row).
 
 Masking is position-based and length-aware (kernels/ref.py semantics):
 unwritten cache slots carry the ``+1e9`` sentinel position and are never
@@ -45,19 +48,34 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, scale: float, causal: bool,
-            window: int, nk: int):
+def _kernel(layer_ref, qpos_ref, kpos_ref, q_ref, *refs, scale: float,
+            causal: bool, window: int, nk: int, prefix: bool):
+    if prefix:
+        pk_ref, pv_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     b, j = pl.program_id(0), pl.program_id(2)
+    q = q_ref[...].astype(jnp.float32)                      # (g, D)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if prefix:               # always-visible prefix slots open the state
+            s = jax.lax.dot_general(
+                q, pk_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # (g, n_p)
+            m = jnp.max(s, axis=-1)[:, None]
+            p = jnp.exp(s - m)
+            m_ref[...] = m
+            l_ref[...] = jnp.sum(p, axis=-1)[:, None]
+            acc_ref[...] = jax.lax.dot_general(
+                p, pv_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[...].astype(jnp.float32)                      # (g, Dp)
-    k = k_ref[...].astype(jnp.float32)                      # (bkv, Dp)
+    k = k_ref[...].astype(jnp.float32)                      # (bkv, D)
     v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -89,6 +107,21 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     def _done():
         out = acc_new / jnp.maximum(l_new, 1e-30)
         o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _kv_block(T: int, block_kv: int) -> int:
+    """The split-KV chunk for a cache of ``T`` slots: the whole cache when
+    it fits one chunk, else the largest multiple of 8 up to ``block_kv``
+    that divides ``T`` (the allocator, ``ops.decode_slots``, makes
+    ``block_kv`` itself divide it), so no call pads the cache."""
+    if T <= block_kv:
+        return T
+    for b in range(block_kv - block_kv % 8, 7, -8):
+        if T % b == 0:
+            return b
+    raise ValueError(f"a decode cache of {T} slots has no chunk of 8 to "
+                     f"{block_kv} slots that divides it: allocate it with "
+                     "ops.decode_slots")
 
 
 def _pad(x, axis, mult, value=0):
@@ -213,57 +246,75 @@ def flash_decode_paged_pallas(q, k_pool, v_pool, table, *, q_pos,
     return out[..., :D].reshape(B, Hq, D)
 
 
+def _prefix_spec(shape):
+    """One KV head's prefix slots: a bank shared by every row
+    (Hkv, n_p, D) or one per row (B, Hkv, n_p, D)."""
+    n_p, D = shape[-2:]
+    if len(shape) == 3:
+        return pl.BlockSpec((None, n_p, D),
+                            lambda b, h, j, lay, qpos: (h, 0, 0))
+    return pl.BlockSpec((None, None, n_p, D),
+                        lambda b, h, j, lay, qpos: (b, h, 0, 0))
+
+
 @functools.partial(jax.jit, static_argnames=(
     "window", "causal", "scale", "block_kv", "interpret"))
-def flash_decode_pallas(q, k, v, *, q_pos, kv_pos, window: int = 0,
-                        causal: bool = True, scale: Optional[float] = None,
-                        block_kv: int = 256, interpret: bool = False):
-    """q: (B, Hq, D); k, v: (B, T, Hkv, D); q_pos: () or (B,);
-    kv_pos: (T,) or (B, T). Returns (B, Hq, D) in q.dtype."""
+def flash_decode_pallas(q, k, v, layer, *, q_pos, kv_pos, prefix_k=None,
+                        prefix_v=None, window: int = 0, causal: bool = True,
+                        scale: Optional[float] = None, block_kv: int = 256,
+                        interpret: bool = False):
+    """Decode attention read in place from the resident cache.
+
+    q: (B, Hq, D); k, v: the layer-stacked cache (L, B, Hkv, T, D), of
+    which layer ``layer`` (a (1,) int32, scalar-prefetched into the K/V
+    index_maps) is read; q_pos: () or (B,); kv_pos: (T,) or (B, T) that
+    layer's slot positions; prefix_k/v: (Hkv, n_p, D) shared or
+    (B, Hkv, n_p, D) per row, folded in as always-visible slots before the
+    chunk sweep. Returns (B, Hq, D) in q.dtype."""
     B, Hq, D = q.shape
-    _, T, Hkv, _ = k.shape
+    _, _, Hkv, T, _ = k.shape
     g = Hq // Hkv
     scale = scale if scale is not None else D ** -0.5
-    bkv = min(block_kv, T)
+    bkv = _kv_block(T, block_kv)
+    nk = T // bkv
+    prefix = prefix_k is not None
 
     with jax.named_scope("kv_cache"):
-        Dp = max(128, D + (-D) % 128)
-        qp4 = _pad(q.reshape(B, Hkv, g, D), 3, Dp)
-        kp = _pad(_pad(k, 1, bkv), 3, Dp)
-        vp = _pad(_pad(v, 1, bkv), 3, Dp)
-        Tp = kp.shape[1]
-        nk = Tp // bkv
-        kp = kp.reshape(B, Tp, Hkv * Dp)
-        vp = vp.reshape(B, Tp, Hkv * Dp)
+        q4 = q.reshape(B, Hkv, g, D)
         qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
-        kvpos = _pad(jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32),
-                                      (B, T)),
-                     1, bkv, value=10 ** 9).reshape(B, nk, bkv)  # invisible
+        kvpos = jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32),
+                                 (B, T)).reshape(B, nk, bkv)
 
+    kv_spec = pl.BlockSpec((None, None, None, bkv, D),
+                           lambda b, h, j, lay, qpos: (lay[0], b, h, j, 0))
+    in_specs = [
+        pl.BlockSpec((None, nk, bkv), lambda b, h, j, lay, qpos: (b, 0, 0)),
+        pl.BlockSpec((None, None, g, D),
+                     lambda b, h, j, lay, qpos: (b, h, 0, 0)),
+    ]
+    operands = [kvpos, q4]
+    if prefix:
+        p_spec = _prefix_spec(prefix_k.shape)
+        in_specs += [p_spec, p_spec]
+        operands += [prefix_k, prefix_v]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((None, nk, bkv), lambda b, h, j, qpos: (b, 0, 0)),
-            pl.BlockSpec((None, None, g, Dp),
-                         lambda b, h, j, qpos: (b, h, 0, 0)),
-            pl.BlockSpec((None, bkv, Dp), lambda b, h, j, qpos: (b, j, h)),
-            pl.BlockSpec((None, bkv, Dp), lambda b, h, j, qpos: (b, j, h)),
-        ],
-        out_specs=pl.BlockSpec((None, None, g, Dp),
-                               lambda b, h, j, qpos: (b, h, 0, 0)),
+        in_specs=in_specs + [kv_spec, kv_spec],
+        out_specs=pl.BlockSpec((None, None, g, D),
+                               lambda b, h, j, lay, qpos: (b, h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, Dp), jnp.float32),
+            pltpu.VMEM((g, D), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal,
-                          window=window, nk=nk),
+                          window=window, nk=nk, prefix=prefix),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dp), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=interpret,
         name="flash_decode",
-    )(qpos, kvpos, qp4, kp, vp)
-    return out[..., :D].reshape(B, Hq, D)
+    )(layer, qpos, *operands, k, v)
+    return out.reshape(B, Hq, D)

@@ -333,52 +333,74 @@ def set_decode_block(bkv: int) -> None:
     _DECODE_BKV = bkv
 
 
+def decode_slots(n: int) -> int:
+    """Slots to allocate for a decode cache that holds ``n`` positions:
+    ``n`` when it fits one split-KV chunk, else ``n`` rounded up to a
+    multiple of the chunk. The extra slots keep the ``+1e9`` sentinel, so
+    the kernel reads the cache as it lies and no call pads it."""
+    b = _DECODE_BKV
+    return n if n <= b else -(-n // b) * b
+
+
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
                  q_pos: jax.Array, kv_pos: jax.Array,
+                 layer: Optional[jax.Array] = None,
                  prefix_k: Optional[jax.Array] = None,
                  prefix_v: Optional[jax.Array] = None,
                  window: int = 0, causal: bool = True,
                  scale: Optional[float] = None,
                  block_kv: Optional[int] = None,
                  backend: Optional[str] = None) -> jax.Array:
-    """One decode token per sequence against a KV cache (+ prefix bank).
+    """One decode token per sequence against the resident KV cache.
 
-    q: (B, Hq, D); k, v: (B, T, Hkv, D); q_pos: scalar or (B,);
-    kv_pos: (T,) or (B, T) cache-slot positions (``+1e9`` sentinel marks
-    unwritten slots — length-aware masking keeps them invisible).
+    q: (B, Hq, D); k, v: one layer's cache (B, Hkv, T, D), or with
+    ``layer`` (a scalar index) the layer-stacked cache (L, B, Hkv, T, D)
+    read in place; q_pos: scalar or (B,); kv_pos: (T,), (B, T) or, with
+    ``layer``, the stacked (L, B, T) slot positions (``+1e9`` sentinel
+    marks unwritten slots — length-aware masking keeps them invisible).
     prefix_k/v: (n_p, Hkv, D) or (B, n_p, Hkv, D) always-visible learned
     slots (prefix-KV prompts; position < 0 in the shared semantics).
     Returns (B, Hq, D) in q.dtype.
     """
     block_kv = block_kv or _DECODE_BKV
     impl = _pick(backend)
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
+    kv_pos = jnp.asarray(kv_pos, jnp.int32)
+    if kv_pos.ndim == 3:
+        with jax.named_scope("kv_cache"):
+            kv_pos = kv_pos[layer]
     if impl in ("pallas", "interpret"):
         from repro.kernels import flash_decode as fdk
-        B, T = k.shape[0], k.shape[1]
-        with jax.named_scope("kv_cache"):    # the kernel's one K/V bank
-            if prefix_k is not None:
-                pk, pv = _broadcast_prefix(prefix_k, prefix_v, B)
-                n_p = pk.shape[1]
-                k = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
-                v = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-                kv_pos = jnp.concatenate(
-                    [jnp.full((B, n_p), -1, jnp.int32),
-                     jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32),
-                                      (B, T))], axis=1)
-            T = k.shape[1]
+        B, T = k.shape[1], k.shape[3]
+        pk = pv = None
+        with jax.named_scope("kv_cache"):
             qp = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
-            kp = jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T))
-        bhd, bthd = ("batch", "heads", None), ("batch", None, "heads", None)
-        return _on_mesh(
-            lambda q, k, v, qp, kp: fdk.flash_decode_pallas(
-                q, k, v, q_pos=qp, kv_pos=kp, window=window, causal=causal,
-                scale=scale, block_kv=block_kv,
-                interpret=(impl == "interpret")),
-            (q, k, v, qp, kp),
-            (bhd, bthd, bthd, ("batch",), ("batch", None)), bhd)
-    return _flash_decode_xla(q, k, v, q_pos=q_pos, kv_pos=kv_pos,
-                             prefix_k=prefix_k, prefix_v=prefix_v,
-                             window=window, causal=causal, scale=scale)
+            kp = jnp.broadcast_to(kv_pos, (B, T))
+            lay = jnp.asarray(layer, jnp.int32).reshape(1)
+            if prefix_k is not None:       # (.., n_p, Hkv, D) -> head-major
+                pk = jnp.swapaxes(prefix_k, -3, -2).astype(k.dtype)
+                pv = jnp.swapaxes(prefix_v, -3, -2).astype(v.dtype)
+        bhd = ("batch", "heads", None)
+        cache = (None, "batch", "heads", None, None)
+        args = [q, k, v, lay, qp, kp]
+        axes = [bhd, cache, cache, (None,), ("batch",), ("batch", None)]
+        if pk is not None:
+            pax = ("heads", None, None) if pk.ndim == 3 \
+                else ("batch", "heads", None, None)
+            args += [pk, pv]
+            axes += [pax, pax]
+
+        def kernel(q, k, v, lay, qp, kp, pk=None, pv=None):
+            return fdk.flash_decode_pallas(
+                q, k, v, lay, q_pos=qp, kv_pos=kp, prefix_k=pk, prefix_v=pv,
+                window=window, causal=causal, scale=scale,
+                block_kv=block_kv, interpret=(impl == "interpret"))
+        return _on_mesh(kernel, tuple(args), tuple(axes), bhd)
+    return _flash_decode_xla(q, k[layer], v[layer], q_pos=q_pos,
+                             kv_pos=kv_pos, prefix_k=prefix_k,
+                             prefix_v=prefix_v, window=window, causal=causal,
+                             scale=scale)
 
 
 def _broadcast_prefix(prefix_k, prefix_v, B):
@@ -391,6 +413,7 @@ def _broadcast_prefix(prefix_k, prefix_v, B):
 def _flash_decode_xla(q, k, v, *, q_pos, kv_pos, prefix_k, prefix_v,
                       window, causal, scale):
     """Decode attention in XLA: native-dtype dots with f32 accumulation.
+    k, v: one layer's resident cache (B, Hkv, T, D).
 
     Prefix-KV slots are attended SEPARATELY and merged with an
     online-softmax combine (EXPERIMENTS.md §Perf d2): concatenating n_p
@@ -401,19 +424,19 @@ def _flash_decode_xla(q, k, v, *, q_pos, kv_pos, prefix_k, prefix_v,
     from repro.sharding.rules import shard
 
     B, Hq, D = q.shape
-    T, Hkv = k.shape[1], k.shape[2]
+    Hkv, T = k.shape[1], k.shape[2]
     g = Hq // Hkv
     scale = scale if scale is not None else D ** -0.5
     qf = q.reshape(B, Hkv, g, D)
     qp = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
     kp = jnp.broadcast_to(jnp.asarray(kv_pos, jnp.int32), (B, T))
-    k = shard(k, "batch", "kv_seq", "kv_heads", "head_dim")
-    v = shard(v, "batch", "kv_seq", "kv_heads", "head_dim")
+    k = shard(k, "batch", "kv_heads", "kv_seq", "head_dim")
+    v = shard(v, "batch", "kv_heads", "kv_seq", "head_dim")
 
     def scores(kk, prefix: bool):
         """Masked scores against one KV bank (casting the cache to f32
         before the dot doubles HBM traffic — keep native dtype)."""
-        s = jnp.einsum("bngd,btnd->bngt", qf, kk.astype(qf.dtype),
+        s = jnp.einsum("bngd,bntd->bngt", qf, kk.astype(qf.dtype),
                        preferred_element_type=jnp.float32) * scale
         if prefix:
             return s                              # always fully visible
@@ -424,12 +447,13 @@ def _flash_decode_xla(q, k, v, *, q_pos, kv_pos, prefix_k, prefix_v,
         return jnp.where(vis[:, None, None, :], s, NEG_INF)
 
     def pv(p, vv):
-        return jnp.einsum("bngt,btnd->bngd", p.astype(vv.dtype), vv,
+        return jnp.einsum("bngt,bntd->bngd", p.astype(vv.dtype), vv,
                           preferred_element_type=jnp.float32)
 
-    s_main = scores(k, prefix=False)              # (B, Hkv, g, T) sharded T
+    s_main = scores(k, prefix=False)              # (B, Hkv, g, T)
     if prefix_k is not None:
-        pk, pvv = _broadcast_prefix(prefix_k, prefix_v, B)
+        pk, pvv = (jnp.swapaxes(x, 1, 2)          # head-major (B, Hkv, n_p, D)
+                   for x in _broadcast_prefix(prefix_k, prefix_v, B))
         s_pfx = scores(pk, prefix=True)           # (B, Hkv, g, n_p)
         m = jnp.maximum(jnp.max(s_main, -1), jnp.max(s_pfx, -1))
         e_main = jnp.exp(s_main - m[..., None])
@@ -477,10 +501,10 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                 interpret=(impl == "interpret")),
             (q, k_pool, v_pool, table, qp),
             (bhd, pool, pool, ("batch", None), ("batch",)), bhd)
-    with jax.named_scope("kv_cache"):
+    with jax.named_scope("kv_cache"):        # gathered into the dense layout
         tbl = jnp.clip(table.astype(jnp.int32), 0, nb - 1)
-        k = k_pool[tbl].reshape(B, maxb * bs, Hkv, D)
-        v = v_pool[tbl].reshape(B, maxb * bs, Hkv, D)
+        k = jnp.swapaxes(k_pool[tbl].reshape(B, maxb * bs, Hkv, D), 1, 2)
+        v = jnp.swapaxes(v_pool[tbl].reshape(B, maxb * bs, Hkv, D), 1, 2)
     kv_pos = jnp.arange(maxb * bs, dtype=jnp.int32)
     if impl in ("pallas", "interpret"):           # prefix bank: dense kernel
         return flash_decode(q, k, v, q_pos=q_pos, kv_pos=kv_pos,

@@ -13,6 +13,12 @@ Modes:
   (kernels/ops.py::flash_decode — split-KV Pallas kernel on TPU, blocked
   XLA online-softmax elsewhere); the cache is updated in place at ``pos``
   (or slot ``pos % window`` for sliding).
+
+The dense cache is resident in one head-major layout: K and V are
+``(B, Hkv, T, D)`` per layer, ``(L, B, Hkv, T, D)`` stacked, so a KV
+head's ``(chunk, D)`` tile is a block of the stored array. The prefill
+writer, the per-token writer and the kernel all use it as it lies; T is
+allocated by ``ops.decode_slots`` so the kernel never pads it.
 """
 from __future__ import annotations
 
@@ -92,10 +98,11 @@ def _qkv(params, adapters, x, cfg: ModelConfig, kv_x=None, adapter_ids=None):
 
 
 @jax.named_scope("kv_cache")
-def _with_prefix(k, v, adapters, B, adapter_ids=None):
+def _with_prefix(k, v, adapters, B, adapter_ids=None, axis: int = 1):
     """Prepend per-layer prefix-KV slots (broadcast over batch; with
     ``adapter_ids`` each row gathers its own domain's slots from the
-    stacked (n_slots, n_p, Hkv, D) bank)."""
+    stacked (n_slots, n_p, Hkv, D) bank) along the slot ``axis``: 1 for
+    (B, S, Hkv, D), 2 for the head-major cache (B, Hkv, T, D)."""
     pfx = (adapters or {}).get("prefix")
     if pfx is None:
         return k, v, 0
@@ -108,7 +115,10 @@ def _with_prefix(k, v, adapters, B, adapter_ids=None):
         pv = jnp.broadcast_to(pfx["v"][None],
                               (B, *pfx["v"].shape)).astype(v.dtype)
     n_p = pk.shape[1]
-    return jnp.concatenate([pk, k], 1), jnp.concatenate([pv, v], 1), n_p
+    if axis == 2:
+        pk, pv = jnp.swapaxes(pk, 1, 2), jnp.swapaxes(pv, 1, 2)
+    return (jnp.concatenate([pk, k], axis), jnp.concatenate([pv, v], axis),
+            n_p)
 
 
 # ---------------------------------------------------------------------------
@@ -168,8 +178,10 @@ def attention_seq(params: dict, adapters: Optional[dict], x: jax.Array,
 
 @jax.named_scope("kv_cache")
 def _prefill_cache(k, v, positions, S: int, cache_len, window: int, lengths):
-    """The layer's decode cache from its prompt K/V: a rolling buffer of
-    ``window`` slots for the sliding variant, else ``cache_len`` slots."""
+    """The layer's decode cache from its prompt K/V (B, S, Hkv, D), in the
+    resident head-major layout (B, Hkv, T, D): a rolling buffer of
+    ``window`` slots for the sliding variant, else ``cache_len`` slots,
+    each rounded up by ``ops.decode_slots`` with sentinel slots."""
     B = k.shape[0]
     lens = jnp.full((B,), S, jnp.int32) if lengths is None \
         else lengths.astype(jnp.int32)
@@ -183,29 +195,27 @@ def _prefill_cache(k, v, positions, S: int, cache_len, window: int, lengths):
                                   // W)                # (B, W)
         valid = p >= 0
         gidx = jnp.clip(p, 0, S - 1)[:, :, None, None]
-        cache_k = jnp.where(valid[:, :, None, None],
-                            jnp.take_along_axis(k, gidx, axis=1),
-                            jnp.zeros((), k.dtype))
-        cache_v = jnp.where(valid[:, :, None, None],
-                            jnp.take_along_axis(v, gidx, axis=1),
-                            jnp.zeros((), v.dtype))
+        k = jnp.where(valid[:, :, None, None],
+                      jnp.take_along_axis(k, gidx, axis=1),
+                      jnp.zeros((), k.dtype))
+        v = jnp.where(valid[:, :, None, None],
+                      jnp.take_along_axis(v, gidx, axis=1),
+                      jnp.zeros((), v.dtype))
         # +1e9 sentinel: empty slots must be *invisible* (negative would
         # mark them as always-visible prefix slots in the mask rules)
         cpos = jnp.where(valid, p, 10 ** 9)
-        cache = {"k": cache_k, "v": cache_v, "pos": cpos}
+        T = kops.decode_slots(W)
     else:
-        L = max(cache_len or S, S)
-        pad = L - S
-        base = jnp.pad(positions.astype(jnp.int32), (0, pad),
-                       constant_values=10 ** 9)        # (L,)
-        cpos = jnp.where(jnp.arange(L)[None, :] < lens[:, None],
-                         base[None, :], 10 ** 9)       # (B, L)
-        cache = {
-            "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-            "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-            "pos": cpos,
-        }
-    return cache
+        T = kops.decode_slots(max(cache_len or S, S))
+        cpos = jnp.where(jnp.arange(S)[None, :] < lens[:, None],
+                         positions.astype(jnp.int32)[None, :], 10 ** 9)
+    n = k.shape[1]
+    slots = ((0, 0), (0, 0), (0, T - n), (0, 0))
+    return {
+        "k": jnp.pad(jnp.swapaxes(k, 1, 2), slots),
+        "v": jnp.pad(jnp.swapaxes(v, 1, 2), slots),
+        "pos": jnp.pad(cpos, ((0, 0), (0, T - n)), constant_values=10 ** 9),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -218,10 +228,16 @@ def attention_decode(params: dict, adapters: Optional[dict], x: jax.Array,
                      window: int = 0, cross: bool = False,
                      use_rope: bool = True,
                      adapter_ids: Optional[jax.Array] = None,
-                     active: Optional[jax.Array] = None):
+                     active: Optional[jax.Array] = None,
+                     layer: Optional[jax.Array] = None):
     """x: (B, 1, d). cache: {'k','v','pos'} (+ static for cross). Returns
     (out, new_cache). ``adapter_ids`` selects each row's adapter from
     stacked (n_slots, ...) adapter leaves (multi-tenant serving).
+
+    A dense cache is the layer-stacked resident buffer — K/V
+    (L, B, Hkv, T, D), ``pos`` (L, B, T) — written and read in place at
+    the scalar index ``layer``: the only cache bytes the step writes are
+    each live row's new token, and ``new_cache`` is the whole stack.
 
     ``pos`` is a scalar or per-row (B,) position: each row writes its own
     cache slot ``pos[b]`` (``pos[b] % window`` for sliding), so one wave
@@ -278,30 +294,35 @@ def attention_decode(params: dict, adapters: Optional[dict], x: jax.Array,
                     v1[:, 0].astype(cache["v"].dtype), mode="drop")
                 new_cache = {"k": k, "v": v, "table": table}
                 kv_pos = None            # implicit: slot index == position
-            else:
-                T = cache["k"].shape[1]
+            else:                        # the new token at [layer, row, slot]
+                T = cache["k"].shape[3]
                 slot = (pos % window) if window and window > 0 else pos
                 if active is not None:   # retired rows: write out of bounds
                     slot = jnp.where(active, slot, T)
                 rows = jnp.arange(B)
-                k = cache["k"].at[rows, slot].set(
+                # one D-vector per (row, head): a window along the minor
+                # dim alone, which the head-major layout stores as is
+                at = (layer, rows[:, None], jnp.arange(nkv)[None, :],
+                      slot[:, None])
+                k = cache["k"].at[at].set(
                     k1[:, 0].astype(cache["k"].dtype), mode="drop")
-                v = cache["v"].at[rows, slot].set(
+                v = cache["v"].at[at].set(
                     v1[:, 0].astype(cache["v"].dtype), mode="drop")
-                kv_pos = cache["pos"].at[rows, slot].set(pos, mode="drop")
+                kv_pos = cache["pos"].at[layer, rows, slot].set(
+                    pos, mode="drop")
                 new_cache = {"k": k, "v": v, "pos": kv_pos}
 
     if "table" not in cache:
-        k = shard(k, "batch", "kv_seq", "kv_heads", "head_dim")
-        v = shard(v, "batch", "kv_seq", "kv_heads", "head_dim")
+        k = shard(k, None, "batch", "kv_heads", "kv_seq", "head_dim")
+        v = shard(v, None, "batch", "kv_heads", "kv_seq", "head_dim")
     else:
         k = shard(k, "kv_blocks", None, "kv_heads", "head_dim")
         v = shard(v, "kv_blocks", None, "kv_heads", "head_dim")
 
-    # Single-token attention is kernel-dispatched: the XLA path keeps the
-    # separate prefix bank + online-softmax merge (§Perf d2 — concatenating
-    # prefix slots onto the seq-sharded cache forces a per-layer all-gather),
-    # the Pallas path is the split-KV flash-decode kernel
+    # Single-token attention is kernel-dispatched; both paths take the
+    # prefix bank as its own operand and merge it into the online softmax
+    # (§Perf d2 — concatenating prefix slots onto the cache would copy it
+    # every layer), the Pallas path being the split-KV flash-decode kernel
     # (kernels/flash_decode.py) with length-aware sentinel masking.
     pfx = (adapters or {}).get("prefix") if not cross else None
     pfx_k = pfx_v = None
@@ -318,7 +339,7 @@ def attention_decode(params: dict, adapters: Optional[dict], x: jax.Array,
             prefix_k=pfx_k, prefix_v=pfx_v)
     else:
         o = kops.flash_decode(
-            q[:, 0], k, v, q_pos=pos.astype(jnp.int32),
+            q[:, 0], k, v, layer=layer, q_pos=pos.astype(jnp.int32),
             kv_pos=kv_pos.astype(jnp.int32),
             prefix_k=pfx_k, prefix_v=pfx_v,
             window=0 if cross else window, causal=not cross)
@@ -381,20 +402,20 @@ def attention_verify(params: dict, adapters: Optional[dict], x: jax.Array,
         q = rope(q, qpos, cfg.rope_theta)
         k1 = rope(k1, qpos, cfg.rope_theta)
 
-    S = cache["k"].shape[1]
+    S = cache["k"].shape[2]
     slot = chunk_slots(qpos, window, S, active)
     rows = jnp.arange(B)[:, None]
     with jax.named_scope("kv_cache"):
-        k = cache["k"].at[rows, slot].set(k1.astype(cache["k"].dtype),
-                                          mode="drop")
-        v = cache["v"].at[rows, slot].set(v1.astype(cache["v"].dtype),
-                                          mode="drop")
+        k = cache["k"].at[rows, :, slot].set(k1.astype(cache["k"].dtype),
+                                             mode="drop")
+        v = cache["v"].at[rows, :, slot].set(v1.astype(cache["v"].dtype),
+                                             mode="drop")
         kv_pos = cache["pos"].at[rows, slot].set(qpos, mode="drop")
     new_cache = {"k": k, "v": v, "pos": kv_pos}
 
-    k = shard(k, "batch", "kv_seq", "kv_heads", "head_dim")
-    v = shard(v, "batch", "kv_seq", "kv_heads", "head_dim")
-    kp, vp, n_p = _with_prefix(k, v, adapters, B, adapter_ids)
+    k = shard(k, "batch", "kv_heads", "kv_seq", "head_dim")
+    v = shard(v, "batch", "kv_heads", "kv_seq", "head_dim")
+    kp, vp, n_p = _with_prefix(k, v, adapters, B, adapter_ids, axis=2)
     if n_p:
         kv_pos = jnp.concatenate(
             [jnp.full((B, n_p), -1, jnp.int32), kv_pos], axis=1)
@@ -405,11 +426,11 @@ def attention_verify(params: dict, adapters: Optional[dict], x: jax.Array,
     vis |= kv_pos[:, None, :] < 0                           # prefix slots
     g = nh // nkv
     qf = q.reshape(B, T, nkv, g, hd).astype(jnp.float32)
-    scores = jnp.einsum("btngd,bsnd->bngts", qf,
+    scores = jnp.einsum("btngd,bnsd->bngts", qf,
                         kp.astype(jnp.float32)) * (hd ** -0.5)
     scores = jnp.where(vis[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bngts,bsnd->btngd", probs, vp.astype(jnp.float32))
+    o = jnp.einsum("bngts,bnsd->btngd", probs, vp.astype(jnp.float32))
     o = o.reshape(B, T, nh * hd).astype(x.dtype)
     y = _proj(o, params["wo"], None, lora.get("o"), lscale, adapter_ids)
     return y, new_cache
@@ -491,10 +512,12 @@ def cache_spec(cfg: ModelConfig, batch: int, seq_len: int, *,
                paged: Optional[tuple] = None) -> dict:
     """ParamSpec tree for a (stacked-over-layers) KV cache.
 
-    The sliding-window cache is a rolling buffer of exactly ``window``
-    slots — what the prefill path actually builds — regardless of how
-    ``seq_len`` compares to the window. ``pos`` is per-row (B, S): each
-    batch row tracks its own written slots (ragged serving).
+    The dense cache is head-major, K/V ``(L, B, Hkv, S, D)``. The
+    sliding-window cache is a rolling buffer of ``window`` slots — what
+    the prefill path actually builds — regardless of how ``seq_len``
+    compares to the window; S is ``ops.decode_slots`` of that (or of
+    ``seq_len``). ``pos`` is per-row (L, B, S): each batch row tracks its
+    own written slots (ragged serving).
 
     ``paged=(n_blocks, block_size)`` describes the PAGED layout instead
     (full-window layers only): a layer-stacked device block pool
@@ -505,7 +528,7 @@ def cache_spec(cfg: ModelConfig, batch: int, seq_len: int, *,
     by construction, so visibility is purely causal."""
     L = layers if layers is not None else cfg.n_layers
     nkv, hd = cfg.n_kv_heads, cfg.head_dim_
-    S = window if window and window > 0 else seq_len
+    S = kops.decode_slots(window if window and window > 0 else seq_len)
     dt = jnp.dtype(cfg.dtype)
     if paged is not None and not (window and window > 0):
         nb, bs = paged
@@ -521,11 +544,11 @@ def cache_spec(cfg: ModelConfig, batch: int, seq_len: int, *,
                                (None, "batch", None), init="zeros"),
         }
     return {
-        "k": ParamSpec((L, batch, S, nkv, hd), dt,
-                       (None, "batch", "kv_seq", "kv_heads", "head_dim"),
+        "k": ParamSpec((L, batch, nkv, S, hd), dt,
+                       (None, "batch", "kv_heads", "kv_seq", "head_dim"),
                        init="zeros"),
-        "v": ParamSpec((L, batch, S, nkv, hd), dt,
-                       (None, "batch", "kv_seq", "kv_heads", "head_dim"),
+        "v": ParamSpec((L, batch, nkv, S, hd), dt,
+                       (None, "batch", "kv_heads", "kv_seq", "head_dim"),
                        init="zeros"),
         "pos": ParamSpec((L, batch, S), jnp.int32, (None, "batch", "kv_seq"),
                          init="zeros"),

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as kops
 from repro.models import attention as attn_mod
 from repro.models.layers import layernorm, layernorm_spec, mlp, mlp_spec
 from repro.models.transformer import (_stack, sublayer_adapter_spec)
@@ -118,9 +119,15 @@ def decode_seq(params: dict, adapters: dict, tok_emb: jax.Array,
             # row-wise)
             from repro.models.attention import _qkv
             _, ck, cv = _qkv(lp["cross"], None, enc_out, cfg, enc_out)
+            Fs = kops.decode_slots(F)        # resident head-major layout
+            slots = ((0, 0), (0, 0), (0, Fs - F), (0, 0))
             cache = {"self": self_cache,
-                     "cross": {"k": ck, "v": cv,
-                               "pos": jnp.broadcast_to(enc_pos, (B, F))}}
+                     "cross": {"k": jnp.pad(jnp.swapaxes(ck, 1, 2), slots),
+                               "v": jnp.pad(jnp.swapaxes(cv, 1, 2), slots),
+                               "pos": jnp.broadcast_to(
+                                   jnp.pad(enc_pos, (0, Fs - F),
+                                           constant_values=10 ** 9),
+                                   (B, Fs))}}
         return x, cache
 
     if remat:
@@ -138,37 +145,41 @@ def decode_step(params: dict, adapters: dict, tok_emb: jax.Array,
     x = tok_emb + jnp.take(params["dec_pos"], pos,
                            axis=0)[:, None].astype(tok_emb.dtype)
 
-    def body(x, layer):
-        lp, la, lc = layer
+    def body(carry, layer):             # caches resident, read in place
+        x, self_cache = carry
+        lp, la, idx = layer
         h, self_cache = attn_mod.attention_decode(
-            lp["self"], la, layernorm(lp["ln1"], x), lc["self"], cfg, pos=pos,
-            use_rope=False, active=active)
+            lp["self"], la, layernorm(lp["ln1"], x), self_cache, cfg,
+            pos=pos, use_rope=False, active=active, layer=idx)
         x = x + h
         h, _ = attn_mod.attention_decode(
-            lp["cross"], None, layernorm(lp["ln2"], x), lc["cross"], cfg,
-            pos=pos, cross=True)
+            lp["cross"], None, layernorm(lp["ln2"], x), caches["cross"], cfg,
+            pos=pos, cross=True, layer=idx)
         x = x + h
         x = x + mlp(lp["mlp"], layernorm(lp["ln3"], x))
-        return x, {"self": self_cache, "cross": lc["cross"]}
+        return (x, self_cache), None
 
-    x, new_caches = jax.lax.scan(
-        body, x, (params["dec"], adapters.get("dec", {}), caches))
-    return x, new_caches
+    L = caches["cross"]["pos"].shape[0]
+    (x, self_cache), _ = jax.lax.scan(
+        body, (x, caches["self"]),
+        (params["dec"], adapters.get("dec", {}),
+         jnp.arange(L, dtype=jnp.int32)))
+    return x, {"self": self_cache, "cross": caches["cross"]}
 
 
 def encdec_cache_spec(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
     L = cfg.n_layers
-    F = cfg.audio.n_audio_frames
+    F = kops.decode_slots(cfg.audio.n_audio_frames)
     return {
         "self": attn_mod.cache_spec(cfg, batch, seq_len, layers=L),
         "cross": {
-            "k": ParamSpec((L, batch, F, cfg.n_kv_heads, cfg.head_dim_),
+            "k": ParamSpec((L, batch, cfg.n_kv_heads, F, cfg.head_dim_),
                            jnp.dtype(cfg.dtype),
-                           (None, "batch", "frames", "kv_heads", "head_dim"),
+                           (None, "batch", "kv_heads", "frames", "head_dim"),
                            init="zeros"),
-            "v": ParamSpec((L, batch, F, cfg.n_kv_heads, cfg.head_dim_),
+            "v": ParamSpec((L, batch, cfg.n_kv_heads, F, cfg.head_dim_),
                            jnp.dtype(cfg.dtype),
-                           (None, "batch", "frames", "kv_heads", "head_dim"),
+                           (None, "batch", "kv_heads", "frames", "head_dim"),
                            init="zeros"),
             "pos": ParamSpec((L, batch, F), jnp.int32,
                              (None, "batch", "frames"), init="zeros"),

@@ -122,9 +122,8 @@ def input_pspec_axes(cfg: ModelConfig, shape: InputShape) -> dict:
 # ``embed``, ``layers`` (the layer scan; ``layer`` is its body), ``lm_head``
 # (final norm, unembedding and the loss) and ``sample``; inside a layer
 # ``attn`` (with ``kv_cache`` and ``lora``) and ``mlp``; ``steps`` around a
-# decode segment's step loop, whose own ops are the copies XLA places
-# around the layer scan. Scopes change only the ops' metadata, never the
-# program.
+# decode segment's step loop, whose own ops are whatever XLA places around
+# the layer scan. Scopes change only the ops' metadata, never the program.
 
 
 @jax.named_scope("embed")
@@ -381,7 +380,9 @@ def _refill_fn(cfg: ModelConfig, cap: int, mesh=None):
     leaf and the surviving rows' generation state stays bitwise
     untouched. This is what makes the drain TRUE continuous batching: a
     freed slot is re-prefilled between scan segments at the cost of a
-    refill-sized prefill, not a wave-sized one."""
+    refill-sized prefill, not a wave-sized one. The wave's caches are
+    donated: the merge writes the admitted rows into them in place, and
+    the caller rebinds them from the result."""
 
     def refill(params, batch, prompt_lens, row_idx, tok, caches, pos,
                adapter_ids):
@@ -399,7 +400,7 @@ def _refill_fn(cfg: ModelConfig, cap: int, mesh=None):
             pos = pos.at[row_idx].set(pos_n, mode="drop")
             return tok, caches, pos
 
-    return jax.jit(refill)
+    return jax.jit(refill, donate_argnums=(5,))
 
 
 # tracelint: keys=cfg,steps,greedy,mesh
@@ -409,7 +410,9 @@ def _segment_fn(cfg: ModelConfig, steps: int, greedy: bool, mesh=None):
 
     Segment lengths are powers of two (the engine buckets them), so the
     jit cache stays O(log max_budget) across any mix of per-row budgets
-    instead of growing per distinct budget."""
+    instead of growing per distinct budget. The caches are donated: the
+    step loop writes each token into them in place, and the caller
+    rebinds them from the result."""
 
     def decode_segment(params, tok, caches, pos, remaining, key,
                        adapter_ids):
@@ -419,7 +422,7 @@ def _segment_fn(cfg: ModelConfig, steps: int, greedy: bool, mesh=None):
                 adapter_ids)
             return toks, tok, caches, pos, remaining, key
 
-    return jax.jit(decode_segment)
+    return jax.jit(decode_segment, donate_argnums=(2,))
 
 
 # -- paged KV cache (block pool + per-row tables) ---------------------------
@@ -429,7 +432,7 @@ def _pool_commit(pool_sub: dict, dense_k, dense_v, tables, lens):
     """Scatter dense prefill K/V for B rows into the block pool.
 
     pool_sub: {'k','v'[,'table']} with pool leaves (L, nb, bs, Hkv, D);
-    dense_k/v: (L, B, S_pad, ...) freshly prefilled rows; tables:
+    dense_k/v: (L, B, Hkv, S_pad, D) freshly prefilled rows; tables:
     (B, maxb) int32 block tables; lens: (B,) valid lengths. Token ``t``
     of row ``b`` lands at ``pool[:, tables[b, t//bs], t%bs]``; tokens at
     or beyond ``lens[b]`` route to the ``nb`` sentinel and drop (pad
@@ -438,15 +441,15 @@ def _pool_commit(pool_sub: dict, dense_k, dense_v, tables, lens):
     :func:`_paged_suffix_fn`). The values written are EXACTLY the dense
     prefill's — which is what keeps paged drains bit-identical."""
     nb, bs = pool_sub["k"].shape[1], pool_sub["k"].shape[2]
-    S_pad = dense_k.shape[2]
+    S_pad = dense_k.shape[3]
     t_idx = jnp.arange(S_pad, dtype=jnp.int32)
     blk = jnp.where(t_idx[None, :] < lens[:, None],
                     tables[:, t_idx // bs], nb)            # (B, S_pad)
     off = jnp.broadcast_to(t_idx % bs, blk.shape)
     k = pool_sub["k"].at[:, blk, off].set(
-        dense_k.astype(pool_sub["k"].dtype), mode="drop")
+        jnp.swapaxes(dense_k, 2, 3).astype(pool_sub["k"].dtype), mode="drop")
     v = pool_sub["v"].at[:, blk, off].set(
-        dense_v.astype(pool_sub["v"].dtype), mode="drop")
+        jnp.swapaxes(dense_v, 2, 3).astype(pool_sub["v"].dtype), mode="drop")
     return k, v
 
 

@@ -235,7 +235,7 @@ def _freeze_inactive(new_cache: dict, old_cache: dict, active):
 
 
 def _apply_decode(kind: str, p: dict, a: dict, x, cache, cfg: ModelConfig, *,
-                  pos, adapter_ids=None, active=None):
+                  pos, adapter_ids=None, active=None, layer=None):
     if kind == "ssm":
         h, new = ssm_mod.ssm_decode(p["mix"], a, rmsnorm(p["ln1"], x), cache,
                                     cfg)
@@ -250,7 +250,7 @@ def _apply_decode(kind: str, p: dict, a: dict, x, cache, cfg: ModelConfig, *,
     h, cache = attn_mod.attention_decode(p["attn"], a, rmsnorm(p["ln1"], x),
                                          cache, cfg, pos=pos, window=w,
                                          adapter_ids=adapter_ids,
-                                         active=active)
+                                         active=active, layer=layer)
     x = x + h
     if kind == "moe":
         h2, _ = moe_apply(p["moe"], rmsnorm(p["ln2"], x), cfg)
@@ -319,29 +319,44 @@ def stack_decode(params: dict, adapters: dict, x: jax.Array,
     """Single-token step through all groups. Returns (x, new_caches).
 
     ``pos`` may be per-row (B,) (ragged serving); ``active`` (B,) bool
-    freezes retired rows' caches while the rest of the wave decodes."""
+    freezes retired rows' caches while the rest of the wave decodes.
+
+    Dense attention caches (those with a ``pos`` plane) are carried whole
+    through the layer scan and written in place at ``[layer, row, slot]``,
+    so no step slices out or writes back a layer's cache; recurrent state
+    and paged pools ride the scan as per-layer slices."""
     new_caches: dict = {}
     for name, kinds, n in groups_for(cfg):
         gp, ga = params[name], adapters.get(name, {})
         gc = caches[name]
+        resident = {key: c for key, c in gc.items() if "pos" in c}
+        sliced = {key: c for key, c in gc.items() if "pos" not in c}
 
         @jax.named_scope("layer")
-        def body(x, layer):
-            lp, la, lc = layer
+        def body(carry, layer):
+            x, res = carry
+            res = dict(res)
+            lp, la, lc, idx = layer
             new_lc = {}
             for i, k in enumerate(kinds):
                 key = f"s{i}"
-                x, c = _apply_decode(k, lp[key], la.get(key, {}), x,
-                                     lc[key], cfg, pos=pos,
-                                     adapter_ids=adapter_ids,
-                                     active=active)
-                new_lc[key] = c
-            return x, new_lc
+                if key in res:
+                    x, res[key] = _apply_decode(
+                        k, lp[key], la.get(key, {}), x, res[key], cfg,
+                        pos=pos, adapter_ids=adapter_ids, active=active,
+                        layer=idx)
+                else:
+                    x, new_lc[key] = _apply_decode(
+                        k, lp[key], la.get(key, {}), x, lc[key], cfg,
+                        pos=pos, adapter_ids=adapter_ids, active=active)
+            return (x, res), new_lc
 
         with jax.named_scope("layers"):
-            x, new_gc = jax.lax.scan(
-                body, x, (gp, ga if ga else _empty_like(gp, n), gc))
-        new_caches[name] = new_gc
+            (x, resident), new_sliced = jax.lax.scan(
+                body, (x, resident),
+                (gp, ga if ga else _empty_like(gp, n), sliced,
+                 jnp.arange(n, dtype=jnp.int32)))
+        new_caches[name] = {**new_sliced, **resident}
     return x, new_caches
 
 

@@ -358,8 +358,9 @@ def test_paged_flash_decode_bit_parity_with_dense(backend):
     q, k_pool, v_pool, table = _paged_operands(B, maxb, bs, Hq, Hkv, D,
                                                n_blocks=16, dtype=jnp.float32)
     q_pos = jnp.asarray([maxb * bs - 1, maxb * bs - 9], jnp.int32)
-    k = k_pool[table].reshape(B, maxb * bs, Hkv, D)
-    v = v_pool[table].reshape(B, maxb * bs, Hkv, D)
+    # the dense resident layout: head-major (B, Hkv, T, D)
+    k = jnp.swapaxes(k_pool[table].reshape(B, maxb * bs, Hkv, D), 1, 2)
+    v = jnp.swapaxes(v_pool[table].reshape(B, maxb * bs, Hkv, D), 1, 2)
     kv_pos = jnp.arange(maxb * bs, dtype=jnp.int32)
     dense = ops.flash_decode(q, k, v, q_pos=q_pos, kv_pos=kv_pos,
                              window=0, causal=True, backend=backend)

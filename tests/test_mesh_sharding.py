@@ -484,9 +484,14 @@ _KERNEL_SPLIT_SCRIPT = textwrap.dedent("""
     run("attention_one_kv_head", lambda q, kv: ops.flash_attention(
         q, kv[:, :, :1], kv[:, :, 1:], q_pos=pos, kv_pos=pos,
         backend="interpret"), q, kv)
+    hm = jnp.swapaxes(kv, 1, 2)              # resident (B, Hkv, T, D)
     run("decode", lambda q, kv: ops.flash_decode(
         q[:, 0], kv, 2 * kv, q_pos=jnp.array([5, 9]), kv_pos=pos,
-        backend="interpret"), q, kv)
+        backend="interpret"), q, hm)
+    pfx = jax.random.normal(k[4], (2, 3, 2, 64))  # per-row prefix bank
+    run("decode_prefix", lambda q, kv, p: ops.flash_decode(
+        q[:, 0], kv, 2 * kv, q_pos=jnp.array([5, 9]), kv_pos=pos,
+        prefix_k=p, prefix_v=-p, backend="interpret"), q, hm, pfx)
     pool = jax.random.normal(k[3], (6, 4, 2, 64))
     tbl = jnp.array([[0, 2, 4, 6], [1, 3, 5, 6]], jnp.int32)
     run("paged", lambda q, p: ops.flash_decode_paged(
@@ -511,13 +516,17 @@ _D, _M = "data", "model"
 _BSHD = (_D, None, _M, None)
 _BHD = (_D, _M, None)
 _POOL = (None, None, _M, None)
+_CACHE = (None, _D, _M, None, None)
 _KERNEL_SPLITS = {
     # q, k, v split by sequence over `data` and by head over `model`
     "attention": [[_BSHD, _BSHD, _BSHD, (None,), (None,), _BSHD]],
     # one KV head cannot split over two model devices: heads stay whole
     "attention_one_kv_head": [[(_D, None, None, None)] * 3
                               + [(None,), (None,), (_D, None, None, None)]],
-    "decode": [[_BHD, _BSHD, _BSHD, (_D,), (_D, None), _BHD]],
+    # the resident cache (L, B, Hkv, T, D) splits rows and whole heads
+    "decode": [[_BHD, _CACHE, _CACHE, (None,), (_D,), (_D, None), _BHD]],
+    "decode_prefix": [[_BHD, _CACHE, _CACHE, (None,), (_D,), (_D, None),
+                       (_D, _M, None, None), (_D, _M, None, None), _BHD]],
     # any row's table may name any block: the pool splits by head only
     "paged": [[_BHD, _POOL, _POOL, (_D, None), (_D,), _BHD]],
     # forward: output columns over `model`; dx contracts the split columns

@@ -12,7 +12,9 @@ The topology is described inside a module fixture (never at import time):
 only one process may load the TPU library, so only the worker that runs
 this file loads it, and every worker still collects the same tests.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,12 +77,17 @@ def test_flash_attention_prefill_2048(shape):
 
 @pytest.mark.parametrize("block_kv", [16, 256])
 def test_flash_decode_dense(shape, block_kv):
-    B, T = 8, 4096
+    """The kernel reads one layer of the resident stacked cache
+    (L, B, Hkv, T, D) in place, with a per-row prefix bank."""
+    B, T, L = 8, 4096, 3
     _assert_kernel(
-        lambda q, k, v, qp, kp: fd.flash_decode_pallas(
-            q, k, v, q_pos=qp, kv_pos=kp, block_kv=block_kv),
-        shape((B, HQ, HD)), shape((B, T, HKV, HD)), shape((B, T, HKV, HD)),
-        shape((B,), I32), shape((B, T), I32))
+        lambda q, k, v, lay, qp, kp, pk, pv: fd.flash_decode_pallas(
+            q, k, v, lay, q_pos=qp, kv_pos=kp, prefix_k=pk, prefix_v=pv,
+            block_kv=block_kv),
+        shape((B, HQ, HD)), shape((L, B, HKV, T, HD)),
+        shape((L, B, HKV, T, HD)), shape((1,), I32), shape((B,), I32),
+        shape((B, T), I32), shape((B, HKV, N_PREFIX, HD)),
+        shape((B, HKV, N_PREFIX, HD)))
 
 
 def test_flash_decode_paged(shape):
@@ -127,6 +134,80 @@ def test_lora_bgmv_seq(shape, S):
             x, w, a, b, ids, 2.0, bias),
         shape((B, S, K)), shape((K, N)), shape((SLOTS, K, RANK)),
         shape((SLOTS, RANK, N)), shape((B,), I32), shape((N,)))
+
+
+# ---------------------------------------------------------------------------
+# The decode step's cache: resident, written in place, donated
+# ---------------------------------------------------------------------------
+
+_RELAYOUT = ("copy", "copy-start", "concatenate", "pad", "transpose",
+             "reshape", "dynamic-slice")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                    r"([\w-]+)\(")
+
+
+def _serving_program(shape, name):
+    """qwen2-7b at published widths, 2 layers, 16 prefix slots: the
+    decode segment (32 rows, cap 2048) or a 4-row refill into it, compiled
+    for one described v5e with the Pallas kernels."""
+    from repro.configs.base import get_config
+    from repro.models import model as M
+    from repro.sharding import rules as R
+    cfg = get_config("qwen2-7b").with_depth(2)
+    assert cfg.peft.n_prefix == N_PREFIX
+    B, cap = 32, 2048
+
+    def place(tree):
+        return jax.tree.map(lambda s: shape(s.shape, s.dtype), tree)
+    params = place(jax.eval_shape(lambda: M.init(cfg, jax.random.PRNGKey(0))))
+    caches = place(R.shape_structs(M.cache_spec(cfg, B, cap)))
+    if name == "segment":
+        low = M._segment_fn(cfg, 4, True).lower(
+            params, shape((B, 1), I32), caches, shape((B,), I32),
+            shape((B,), I32), None, None)
+    else:
+        low = M._refill_fn(cfg, cap).lower(
+            params, {"tokens": shape((4, 512), I32)}, shape((4,), I32),
+            shape((4,), I32), shape((B, 1), I32), caches, shape((B,), I32),
+            None)
+    layer_k = B * cfg.n_kv_heads * cap * cfg.head_dim_
+    return low.compile().as_text(), layer_k, B
+
+
+def _cache_params_aliased(text):
+    """Whether every cache parameter of the entry computation is aliased
+    to an output (donated), and how many there are."""
+    head, entry = text.splitlines()[0], text[text.index("\nENTRY"):]
+    aliased = {int(p) for p in re.findall(r"\}: \((\d+), \{\}", head)}
+    params = {int(n) for n in re.findall(
+        r"%caches\S* = \S+ parameter\((\d+)\)", entry)}
+    return params and params <= aliased, len(params)
+
+
+@pytest.mark.parametrize("program", ["segment", "refill"])
+def test_decode_cache_is_resident_and_donated(shape, program, monkeypatch):
+    """The decode segment writes only the new tokens into the cache: no
+    copy, concatenation, padding, transpose, reshape or slice of cache
+    data in it (a result with the row dim) holds as much as one layer's K
+    cache. The layer scan's slices of the stacked weights carry no row dim
+    and are not the cache's. Segment and refill both take their cache
+    arguments donated (aliased to their cache outputs), so no call copies
+    the cache on entry."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_BACKEND", "pallas")  # the CPU default is xla
+    text, layer_k, rows = _serving_program(shape, program)
+    assert "tpu_custom_call" in text
+    ok, n = _cache_params_aliased(text)
+    assert ok and n == 3, (n, text.splitlines()[0][:400])   # k, v, pos
+    if program == "segment":
+        big = []
+        for line in text.splitlines():
+            m = _INSTR.match(line)
+            dims = [int(d) for d in m.group(2).split(",") if d] if m else []
+            if m and m.group(3) in _RELAYOUT and rows in dims \
+                    and math.prod(dims) >= layer_k:
+                big.append(line.strip()[:160])
+        assert not big, big
 
 
 # ---------------------------------------------------------------------------
