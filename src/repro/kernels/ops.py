@@ -89,8 +89,8 @@ def _pick(b: Optional[str], *, tpu_kernel: bool = True) -> str:
     return impl
 
 
-def _on_mesh(kernel, args, in_axes, out_axes, psum=()):
-    """Call a Pallas kernel inside the active sharding context.
+def _on_mesh(name, kernel, args, in_axes, out_axes, psum=()):
+    """Call the Pallas kernel ``name`` inside the active sharding context.
 
     XLA cannot partition a Mosaic kernel, so under a mesh (a
     ``rules.use_rules`` context: the engine's mesh waves, the mesh HFSL
@@ -104,22 +104,35 @@ def _on_mesh(kernel, args, in_axes, out_axes, psum=()):
     dims, and ``psum`` the split dims the kernel contracts over, whose
     partial results are summed (a list of each for several results).
     Without a mesh this is a plain call.
+
+    Each call under a mesh counts, as it is traced, in the process-wide
+    telemetry: ``kernels.mesh_calls.<name>``, and ``kernels.unsplit.<name>``
+    when a named dim did not divide and the kernel ran whole over that
+    name's mesh axes.
     """
     from repro.sharding.rules import active_rules
     mesh, rules = active_rules()
     if mesh is None:
         return kernel(*args)
     from jax.sharding import PartitionSpec as P
-    taken, split = set(), {}
-    for name in dict.fromkeys(n for ax in in_axes for n in ax if n):
-        tgt = rules.get(name)
+
+    from repro.core import telemetry
+    taken, split, whole = set(), {}, False
+    for dim in dict.fromkeys(n for ax in in_axes for n in ax if n):
+        tgt = rules.get(dim)
         tgt = () if tgt is None else (tgt,) if isinstance(tgt, str) else tgt
         tgt = tuple(a for a in tgt if a in mesh.axis_names and a not in taken)
         k = math.prod(mesh.shape[a] for a in tgt)
         if all(x.shape[i] % k == 0 for ax, x in zip(in_axes, args)
-               for i, n in enumerate(ax) if n == name):
-            split[name] = tgt
+               for i, n in enumerate(ax) if n == dim):
+            split[dim] = tgt
             taken.update(tgt)
+        else:
+            whole = True
+    tel = telemetry.get()
+    tel.count(f"kernels.mesh_calls.{name}")
+    if whole:
+        tel.count(f"kernels.unsplit.{name}")
 
     def spec(ax):
         return P(*(split.get(n) or None for n in ax))
@@ -184,6 +197,7 @@ def _flash_kernel(impl, window, causal, scale, block_q, block_kv,
     qp, kp = jnp.asarray(q_pos, jnp.int32), jnp.asarray(kv_pos, jnp.int32)
     bshd = ("batch", None, "heads", None)
     return _on_mesh(
+        "flash_attention",
         lambda q, k, v, qp, kp: fk.flash_attention_pallas(
             q, k, v, q_pos=qp, kv_pos=kp, window=window, causal=causal,
             scale=scale, block_q=block_q, block_kv=block_kv,
@@ -396,7 +410,8 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 q, k, v, lay, q_pos=qp, kv_pos=kp, prefix_k=pk, prefix_v=pv,
                 window=window, causal=causal, scale=scale,
                 block_kv=block_kv, interpret=(impl == "interpret"))
-        return _on_mesh(kernel, tuple(args), tuple(axes), bhd)
+        return _on_mesh("flash_decode", kernel, tuple(args), tuple(axes),
+                        bhd)
     return _flash_decode_xla(q, k[layer], v[layer], q_pos=q_pos,
                              kv_pos=kv_pos, prefix_k=prefix_k,
                              prefix_v=prefix_v, window=window, causal=causal,
@@ -496,6 +511,7 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         qp = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32), (B,))
         bhd, pool = ("batch", "heads", None), (None, None, "heads", None)
         return _on_mesh(                 # any row may name any pool block
+            "flash_decode_paged",
             lambda q, kp, vp, t, qp: fdk.flash_decode_paged_pallas(
                 q, kp, vp, t, q_pos=qp, scale=scale,
                 interpret=(impl == "interpret")),
@@ -710,7 +726,7 @@ def _lora_forward(impl, scale, x, w, a, b, bias, contract_split=False):
                     (None, "heads")]
             out, red = ("batch", "heads"), ()
         ops_ = (x, w, a, b) + (() if bias is None else (bias,))
-        return _on_mesh(lambda *o: lk.lora_matmul_pallas(
+        return _on_mesh("lora_matmul_fwd", lambda *o: lk.lora_matmul_pallas(
             *o[:4], scale, *o[4:], interpret=interp),
             ops_, axes + [("heads",)][:len(ops_) - 4], out, red)
     return _lora_xla(x, w, a, b, scale, bias)
@@ -737,8 +753,10 @@ def _lora_bwd_rule(impl, scale, res, dy):
     if impl in ("pallas", "interpret"):
         from repro.kernels import lora_matmul as lk
         interp = impl == "interpret"
-        da, db = _on_mesh(lambda *o: lk.lora_matmul_bwd_pallas(
-            *o, scale, interpret=interp), (x2, dy2, a, b),
+        da, db = _on_mesh(
+            "lora_matmul_bwd",
+            lambda *o: lk.lora_matmul_bwd_pallas(*o, scale, interpret=interp),
+            (x2, dy2, a, b),
             (("batch", None), ("batch", "heads"), (None, None),
              (None, "heads")),
             [(None, None), (None, "heads")], [("batch", "heads"), ("batch",)])
@@ -805,7 +823,8 @@ def lora_bgmv(x, w, a, b, adapter_ids, scale: float = 1.0, bias=None, *,
         ops_ = (xk, w, a, b, ids) + (() if bias is None else (bias,))
         axes = [rows, (None, "heads"), (None, None, None),
                 (None, None, "heads"), ("batch",), ("heads",)]
-        out = _on_mesh(lambda *o: kern(*o[:5], float(scale), *o[5:],
+        out = _on_mesh("lora_bgmv_seq" if seq else "lora_bgmv_rows",
+                       lambda *o: kern(*o[:5], float(scale), *o[5:],
                                        interpret=interp),
                        ops_, axes[:len(ops_)], rows[:-1] + ("heads",))
         return out if seq else out.reshape(*shp[:-1], w.shape[-1])

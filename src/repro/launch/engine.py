@@ -56,6 +56,7 @@ by the very next dispatch.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from collections import deque
 from typing import Optional
@@ -78,6 +79,16 @@ def _pow2ceil(n: int) -> int:
 
 def _pow2floor(n: int) -> int:
     return 1 << (int(n).bit_length() - 1)
+
+
+def _cache_gauges(tel: Telemetry, caches) -> None:
+    """The resident cache's bytes, whole and on each device (read from
+    the arrays' shardings, so nothing waits on the device)."""
+    leaves = jax.tree.leaves(caches)
+    tel.gauge("engine.cache_bytes", sum(x.nbytes for x in leaves))
+    tel.gauge("engine.cache_bytes_per_device", sum(
+        math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+        for x in leaves))
 
 
 def _uids(packed) -> list[int]:
@@ -736,6 +747,8 @@ class DecodeEngine:
                         tok, caches, pos = M._wave_prefill_fn(
                             self.cfg, cap, self.mesh)(
                             wp, batch, jnp.asarray(lens), ids)
+                        if self.mesh is not None:
+                            _cache_gauges(tel, caches)
                         if self.spec is not None:
                             # drafter rides the same wave: its own prefill
                             # builds the recurrent draft state per row (its

@@ -110,6 +110,23 @@ def test_lora_matmul_forward(shape, M):
         shape((N,)))
 
 
+@pytest.mark.parametrize("M", [128, 2048])      # decode rows, prefill rows
+def test_lora_matmul_split_columns_read_in_place(shape, M):
+    """q's columns split four ways (896 a chip, not a multiple of the
+    512-column tile) take one lane-aligned tile: the kernel reads w as it
+    lies, with no padded copy of it per call."""
+    K, N = D_MODEL, HQ * HD // 4
+    text = jax.jit(lambda x, w, a, b, bias: lm.lora_matmul_pallas(
+        x, w, a, b, 2.0, bias)).lower(
+        shape((M, K)), shape((K, N)), shape((K, RANK)), shape((RANK, N)),
+        shape((N,))).compile().as_text()
+    assert "tpu_custom_call" in text
+    tiled = f"{K},{-(-N // 512) * 512}"             # w padded to the tile
+    padded = [line.strip()[:120] for line in text.splitlines()
+              if (m := _INSTR.match(line)) and m.group(2) == tiled]
+    assert not padded, padded
+
+
 def test_lora_matmul_backward(shape):
     M, K, N = 2048, D_MODEL, D_MODEL
     _assert_kernel(
@@ -298,3 +315,97 @@ def test_mesh_program_compiles_with_kernels(mesh_setup, program,
                       for x in jax.tree.leaves(params))
     per_chip = compiled.memory_analysis().argument_size_in_bytes
     assert 0.2 < per_chip / model_bytes < 0.3
+
+
+# ---------------------------------------------------------------------------
+# The four-chip decode path: segment and refill of a 128-row wave
+# ---------------------------------------------------------------------------
+
+_COLLECTIVE = re.compile(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* "
+                         r"(all-reduce|all-gather|reduce-scatter|"
+                         r"collective-permute|all-to-all)(?:-start)?\(")
+
+
+@pytest.fixture(scope="module")
+def mesh_wave(mesh_setup):
+    """The 128-row wave at cap 2048 as the engine holds it after its first
+    prefill on the described 1x4 mesh: (tok, caches, pos) placed as that
+    program's outputs are."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.kernels import ops
+    from repro.models import model as M
+    mesh, cfg, params = mesh_setup
+    B, rows = 128, NamedSharding(mesh, P("data"))
+    tokens = jax.ShapeDtypeStruct((B, 256), I32, sharding=rows)
+    lens = jax.ShapeDtypeStruct((B,), I32, sharding=rows)
+    prev, ops._BACKEND = ops._BACKEND, "pallas"
+    try:
+        prefill = M._wave_prefill_fn(cfg, 2048, mesh)
+        out = prefill.lower(params, {"tokens": tokens}, lens, None).compile()
+        wave = jax.eval_shape(prefill, params, {"tokens": tokens}, lens, None)
+    finally:
+        ops._BACKEND = prev
+    return _placed(wave, out.output_shardings)
+
+
+@pytest.mark.parametrize("program", ["segment", "refill"])
+def test_mesh_decode_cache_split_and_donated(mesh_setup, mesh_wave, program,
+                                             monkeypatch):
+    """qwen2-7b widths, 2 layers, a 128-row wave at cap 2048 on four
+    described chips: the decode segment, or a refill of 8 rows x 256 into
+    the wave. The cache arguments are donated (aliased to the outputs);
+    each chip holds a quarter of the cache (one KV head; only the small
+    position plane is whole on every chip); and no collective moves as
+    much as one layer's K cache of a chip or the wave's float32 logits:
+    the all-reduces carry activations, and the greedy argmax over the
+    vocabulary-split logits gathers each chip's (max, index). Every
+    collective lies under a named scope that says what caused it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.kernels import ops
+    from repro.models import model as M
+    monkeypatch.setattr(ops, "_BACKEND", "pallas")  # the CPU default is xla
+    mesh, cfg, params = mesh_setup
+    tok, caches, pos = mesh_wave
+    if program == "segment":
+        low = M._segment_fn(cfg, 4, True, mesh).lower(
+            params, tok, caches, pos, pos, None, None)
+    else:
+        rows = NamedSharding(mesh, P("data"))
+        r = lambda *s: jax.ShapeDtypeStruct(s, I32, sharding=rows)
+        low = M._refill_fn(cfg, 2048, mesh).lower(
+            params, {"tokens": r(8, 256)}, r(8), r(8), tok, caches, pos, None)
+    compiled = low.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    head, entry = text.splitlines()[0], text[text.index("\nENTRY"):]
+    aliased = {int(p) for p in re.findall(r"\}: \((\d+), \{\}", head)}
+    cache_params = {int(n) for n in re.findall(
+        r"parameter\((\d+)\).*op_name=\"caches\[", entry)}
+    assert len(cache_params) == 3 and cache_params <= aliased, \
+        (cache_params, head[:400])                        # k, v, pos
+    leaves = jax.tree.leaves(caches)
+    whole = sum(x.size * x.dtype.itemsize for x in leaves)
+    per_chip = sum(math.prod(x.sharding.shard_shape(x.shape))
+                   * x.dtype.itemsize for x in leaves)
+    assert 0.25 <= per_chip / whole < 0.26
+    B, T = caches["g0"]["s0"]["k"].shape[1], caches["g0"]["s0"]["k"].shape[3]
+    layer_k_chip = B * (cfg.n_kv_heads // 4) * T * cfg.head_dim_ * 2
+    logits_f32 = B * cfg.vocab_size * 4
+    item = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    moved = [(m.group(3), m.group(1), m.group(2)) for m in map(
+        _COLLECTIVE.match, text.splitlines()) if m]
+    assert moved                              # the mesh's all-reduces
+    named = [line for line in text.splitlines() if re.search(
+        r" (?:all-reduce|all-gather|reduce-scatter|collective-permute|"
+        r"all-to-all)(?:-start)?\(", line)]
+    assert len(named) == len(moved), named    # none typed as a tuple
+    unscoped = [line.strip()[:160] for line in named if not re.search(
+        r'op_name="[^"]*/(?:attn|mlp|lm_head|sample|embed|kv_cache|lora)/',
+        line)]
+    assert not unscoped, unscoped             # each names what caused it
+    big = [c for c in moved if item[c[1]] * math.prod(
+        int(d) for d in c[2].split(",") if d) >= min(layer_k_chip,
+                                                    logits_f32)]
+    assert not big, big
